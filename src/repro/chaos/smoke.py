@@ -43,8 +43,11 @@ def main() -> int:
 
     import jax
 
+
     from .runner import run_matrix, run_scenario
     from .scenario import ChaosScenario, default_matrix
+    from ..runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     print(f"devices: {jax.devices()}")
     if args.full:

@@ -80,7 +80,7 @@ import jax
 
 from ..compiler.artifact import CompiledDesign
 from ..obs.trace import coerce_tracer
-from .channels import FifoChannel
+from .channels import FifoChannel, _put
 from .programs import (SOURCE_KEY, ProgramBinding, RoutedOutput,
                        bind_programs)
 from .report import ExecutionReport, build_report
@@ -112,6 +112,19 @@ def _block(token: Any) -> None:
     for leaf in jax.tree_util.tree_leaves(token):
         if hasattr(leaf, "block_until_ready"):
             leaf.block_until_ready()
+
+
+def device_name(device) -> str:
+    """Short stable name of a jax device, e.g. ``tpu:2``."""
+    return f"{device.platform}:{device.id}"
+
+
+def _device_names(token: Any) -> set:
+    names = set()
+    for leaf in jax.tree_util.tree_leaves(token):
+        if isinstance(leaf, jax.Array):
+            names.update(device_name(d) for d in leaf.devices())
+    return names
 
 
 def _estimate_flit_hops(channels: Sequence[FifoChannel], transport) -> int:
@@ -191,14 +204,23 @@ class ExecutionState:
                 raise ValueError(
                     f"device_map covers {len(self.device_map)} logical "
                     f"devices but the partition uses {ndev}")
-        # CI runs host-platform emulation
-        # (``--xla_force_host_platform_device_count``) so logical ==
-        # physical; a bare interpreter with one CPU device still executes
-        # every design correctly — logical placement keeps driving the
-        # traffic accounting, physical arrays just share the one device.
+        # Logical device d runs on pool[device_map[d]].  A map that names a
+        # device the pool lacks is an error, except on a host with a single
+        # device (the CPU test runs), which holds every logical device;
+        # logical placement keeps driving the traffic accounting either
+        # way, and the report records where each logical device ran.
         pool = list(devices) if devices is not None else list(jax.devices())
-        jax_dev = [pool[self.device_map[d] % len(pool)]
-                   for d in range(max(1, ndev))]
+        used = self.device_map[:max(1, ndev)]
+        if len(pool) == 1:
+            jax_dev = [pool[0]] * len(used)
+        else:
+            missing = sorted({d for d in used if d >= len(pool)})
+            if missing:
+                raise ValueError(
+                    f"device_map places logical devices on {missing}, but "
+                    f"only {len(pool)} devices are present")
+            jax_dev = [pool[d] for d in used]
+        self.jax_dev = jax_dev
 
         self.owns_transport = transport is None
         if transport is None:
@@ -227,7 +249,7 @@ class ExecutionState:
                            if rep is not None else 0)
             self.channels.append(FifoChannel(
                 i, ch, assign[ch.src], assign[ch.dst], latency=latency,
-                dst_device=jax_dev[assign[ch.dst] % len(jax_dev)],
+                dst_device=jax_dev[assign[ch.dst]],
                 transport=transport,
                 net_src_dev=self.device_map[assign[ch.src]],
                 net_dst_dev=self.device_map[assign[ch.dst]],
@@ -324,6 +346,8 @@ class ExecutionState:
         self.sink_outputs: Dict[str, List[Any]] = {t: [] for t in self.sinks}
         self.busy_s: Dict[int, float] = {}
         self.dev_fired: Dict[int, int] = {}
+        # Devices each task's output arrays were found on (measured).
+        self.task_devices: Dict[str, set] = {t: set() for t in graph.tasks}
         self.sweeps_done = 0
 
     # -- progress queries ----------------------------------------------------
@@ -478,17 +502,23 @@ class ExecutionState:
                     # reason "mem" mirrors the mem_waits tally exactly.
                     tr.task_wait(sweep, v, self.assign[v], "mem", flow)
                 continue
+            dev = self.assign[v]
             token_in: Dict[str, Any] = {fc.src: fc.pop(sweep)
                                         for fc in in_chs}
+            # Stream items and memory responses are placed on the firing
+            # task's device, so the program runs there.
             if not in_chs and v in binding.source_inputs:
-                token_in[SOURCE_KEY] = binding.source_inputs[v][self.fired[v]]
+                token_in[SOURCE_KEY] = _put(
+                    binding.source_inputs[v][self.fired[v]],
+                    self.jax_dev[dev])
             for mc in self.mem_chs[v]:
-                token_in[mc.stream] = mc.consume(sweep)
-            dev = self.assign[v]
+                token_in[mc.stream] = _put(mc.consume(sweep),
+                                           self.jax_dev[dev])
             t0 = time.perf_counter()
             out = binding.programs[v](token_in)
             _block(out)
             busy = time.perf_counter() - t0
+            self.task_devices[v].update(_device_names(out))
             self.busy_s[dev] = self.busy_s.get(dev, 0.0) + busy
             self.dev_fired[dev] = self.dev_fired.get(dev, 0) + 1
             if tr.enabled:
@@ -519,7 +549,11 @@ class ExecutionState:
             starvation_detail=self.starve_detail, transport=self.transport,
             congestion_waits=self.congestion_waits, memsys=self.memsys,
             mem_channels=self.mem_channels, mem_waits=self.mem_waits,
-            tracer=self.tracer)
+            tracer=self.tracer,
+            placement={d: device_name(jd)
+                       for d, jd in enumerate(self.jax_dev)},
+            task_devices={t: sorted(n)
+                          for t, n in self.task_devices.items()})
         outputs = (self.binding.finalize(self.sink_outputs)
                    if self.binding.finalize is not None
                    else self.sink_outputs)
@@ -613,13 +647,18 @@ def execute(design: CompiledDesign,
             injector: Any = None,
             checkpoint_dir: Optional[str] = None,
             checkpoint_every: Optional[int] = None,
-            tracer: Any = None) -> ExecutionResult:
+            tracer: Any = None,
+            device_map: Optional[Sequence[int]] = None) -> ExecutionResult:
     """Run ``design`` as a multi-device dataflow program.
 
     ``binding`` defaults to the app hook resolved from the graph's name
     (``bind_programs(design.graph, inputs)``); ``inputs`` is that hook's
     numeric spec (shapes / iteration counts / seeds).  ``devices`` overrides
-    the physical jax devices backing the partition's logical devices.
+    the pool of jax devices (default ``jax.devices()``), and
+    ``device_map[logical]`` picks the one each logical device runs on
+    (default: logical device d on ``devices[d]``; ``[0, 0, 0, 0]`` puts a
+    4-device design on one chip).  ``report.placement`` records the
+    choice and ``report.task_devices`` where each task's outputs landed.
     ``fabric`` defaults to the design's fabric (``CompileOptions.fabric``);
     pass ``fabric=None`` to force the ideal transfer path or a
     :class:`~repro.net.fabric.Fabric` to override.  ``net_config`` is the
@@ -644,6 +683,6 @@ def execute(design: CompiledDesign,
         max_sweeps=max_sweeps, starve_limit=starve_limit,
         check_starvation=check_starvation, fabric=fabric,
         net_config=net_config, mem=mem, faults=faults,
-        tracer=tracer).run(
+        tracer=tracer, device_map=device_map).run(
             injector=injector, checkpoint_dir=checkpoint_dir,
             checkpoint_every=checkpoint_every)

@@ -157,6 +157,11 @@ class ExecutionReport:
     task_mem_waits: Dict[str, int] = dataclasses.field(default_factory=dict)
     # Observability (repro.obs): the recorded trace, when one was attached.
     trace: Optional[Any] = None                # obs.Tracer (None if untraced)
+    # Logical device -> the jax device it ran on ("tpu:2"), and the devices
+    # each task's output arrays were found on.
+    placement: Dict[int, str] = dataclasses.field(default_factory=dict)
+    task_devices: Dict[str, List[str]] = dataclasses.field(
+        default_factory=dict)
 
     # One-release deprecation shims for the pre-registry counter names.
     congestion_waits = _deprecated_field(
@@ -274,6 +279,10 @@ class ExecutionReport:
             "device_fired": {str(d): n
                              for d, n in sorted(self.device_fired.items())},
             "starvation_events": dict(self.starvation_events),
+            "placement": {str(d): n
+                          for d, n in sorted(self.placement.items())},
+            "task_devices": {t: list(n)
+                             for t, n in sorted(self.task_devices.items())},
             "comm": {
                 "measured_inter_bytes": self.measured_inter_bytes,
                 "modeled_inter_bytes": self.modeled_inter_bytes,
@@ -327,7 +336,9 @@ def build_report(*, design, channels: Sequence[FifoChannel],
                  memsys=None,
                  mem_channels: Sequence[Any] = (),
                  mem_waits: Optional[Mapping[str, int]] = None,
-                 tracer=None
+                 tracer=None,
+                 placement: Optional[Mapping[int, str]] = None,
+                 task_devices: Optional[Mapping[str, List[str]]] = None
                  ) -> ExecutionReport:
     """Assemble the report from live channels + the design's analytics."""
     part, cluster = design.partition, design.cluster
@@ -425,4 +436,6 @@ def build_report(*, design, channels: Sequence[FifoChannel],
         mem_contention=mem_contention,
         mem_channels=mem_traces,
         task_mem_waits=dict(mem_waits or {}),
-        trace=tracer if getattr(tracer, "enabled", False) else None)
+        trace=tracer if getattr(tracer, "enabled", False) else None,
+        placement=dict(placement or {}),
+        task_devices=dict(task_devices or {}))

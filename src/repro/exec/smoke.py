@@ -1,8 +1,8 @@
 """Executor smoke run (CI): one app on a host-emulated ring.
 
 Compiles the stencil app onto an ``--ndev``-FPGA ring (CI: 4), executes it
-on emulated host devices, asserts numerics parity against the
-single-device Pallas kernel and the measured-vs-predicted comm agreement,
+on emulated host devices, asserts numerics parity against the app's
+single-device reference and the measured-vs-predicted comm agreement,
 and writes the ExecutionReport JSON for the CI artifact.
 
     XLA_FLAGS=--xla_force_host_platform_device_count=4 \
@@ -30,6 +30,7 @@ def main() -> int:
     args = ap.parse_args()
 
     import jax
+
     import jax.numpy as jnp
 
     from ..apps import APPS
@@ -37,6 +38,8 @@ def main() -> int:
     from ..core import fpga_ring_cluster
     from ..obs.trace import Tracer, write_chrome_trace
     from . import bind_programs, execute
+    from ..runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     print(f"devices: {jax.devices()}")
     graph = APPS[args.app].build_graph(args.ndev)

@@ -2,18 +2,21 @@
 
 Arrays are 2-D ``[rows, lanes]`` (vectors of length ``rows × lanes``) so
 the 8×128 VPU tiling gets contiguous sublanes; every kernel tiles rows
-into ``[block_rows, lanes]`` VMEM blocks.  These ops move far more bytes
-than they compute — on the FPGA side each grid step is one shard streaming
-out of its own HBM pseudo-channel, which is exactly how the app graphs
-decompose them (one task per block row-range).
+into ``[block_rows, lanes]`` VMEM blocks, sized to scoped VMEM when the
+caller gives no ``block_rows`` (:mod:`repro.kernels.vmem`).  These ops
+move far more bytes than they compute — on the FPGA side each grid step is
+one shard streaming out of its own HBM pseudo-channel, which is exactly
+how the app graphs decompose them (one task per block row-range).
 """
 from __future__ import annotations
 
-import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from ..vmem import block_rows_for
 
 
 def _axpy_kernel(a_ref, x_ref, y_ref, o_ref):
@@ -21,11 +24,12 @@ def _axpy_kernel(a_ref, x_ref, y_ref, o_ref):
 
 
 def axpy(a: jax.Array, x: jax.Array, y: jax.Array,
-         block_rows: int, interpret: bool = False) -> jax.Array:
-    """a*x + y.  x, y: [R, C]; a: scalar array; R % block_rows == 0."""
+         block_rows: Optional[int] = None,
+         interpret: bool = False) -> jax.Array:
+    """a*x + y.  x, y: [R, C]; a: scalar array."""
     R, C = x.shape
-    block_rows = min(block_rows, R)
-    assert R % block_rows == 0, (R, block_rows)
+    # x, y and the output, double-buffered.
+    block_rows = block_rows_for(R, C * x.dtype.itemsize, 6, block_rows)
     grid = (R // block_rows,)
     return pl.pallas_call(
         _axpy_kernel,
@@ -42,11 +46,13 @@ def axpy(a: jax.Array, x: jax.Array, y: jax.Array,
 
 
 def _dot_partials_kernel(x_ref, y_ref, o_ref):
-    o_ref[0, 0] = jnp.sum(x_ref[...] * y_ref[...])
+    # A [1, 1] vector store: Mosaic cannot store a scalar to VMEM.
+    o_ref[...] = jnp.sum(x_ref[...] * y_ref[...], keepdims=True)
 
 
 def dot_partials(x: jax.Array, y: jax.Array,
-                 block_rows: int, interpret: bool = False) -> jax.Array:
+                 block_rows: Optional[int] = None,
+                 interpret: bool = False) -> jax.Array:
     """Per-block partial sums of x·y: [R, C] → [R // block_rows, 1].
 
     One partial per grid step — the same per-shard partial the app graph's
@@ -54,20 +60,23 @@ def dot_partials(x: jax.Array, y: jax.Array,
     order, fixing the reduction order on both paths.
     """
     R, C = x.shape
-    block_rows = min(block_rows, R)
-    assert R % block_rows == 0, (R, block_rows)
+    # x and y, double-buffered.
+    block_rows = block_rows_for(R, C * x.dtype.itemsize, 4, block_rows)
     nblk = R // block_rows
-    return pl.pallas_call(
+    # Each partial is its own [1, 1] array (a leading squeezed block axis):
+    # a (1, 1) block of an [nblk, 1] array breaks the (8, 128) tiling.
+    out = pl.pallas_call(
         _dot_partials_kernel,
         grid=(nblk,),
         in_specs=[
             pl.BlockSpec((block_rows, C), lambda i: (i, 0)),
             pl.BlockSpec((block_rows, C), lambda i: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nblk, 1), x.dtype),
+        out_specs=pl.BlockSpec((None, 1, 1), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((nblk, 1, 1), x.dtype),
         interpret=interpret,
     )(x, y)
+    return out.reshape(nblk, 1)
 
 
 def fold_partials(partials) -> jax.Array:
@@ -94,12 +103,13 @@ def _gemv_kernel(a_ref, x_ref, o_ref):
     o_ref[...] = jnp.sum(a_ref[...] * x_ref[...], axis=1, keepdims=True)
 
 
-def gemv(A: jax.Array, x: jax.Array,
-         block_rows: int, interpret: bool = False) -> jax.Array:
+def gemv(A: jax.Array, x: jax.Array, block_rows: Optional[int] = None,
+         interpret: bool = False) -> jax.Array:
     """A @ x with row-block tiling.  A: [M, N]; x: [1, N] → [M, 1]."""
     M, N = A.shape
-    block_rows = min(block_rows, M)
-    assert M % block_rows == 0, (M, block_rows)
+    # The A block, double-buffered; x and the [block_rows, 1] output are
+    # small next to it.
+    block_rows = block_rows_for(M, N * A.dtype.itemsize, 2, block_rows)
     grid = (M // block_rows,)
     return pl.pallas_call(
         _gemv_kernel,

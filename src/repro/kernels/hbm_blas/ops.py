@@ -16,35 +16,36 @@ def _on_cpu() -> bool:
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
-def axpy_op(a, x, y, block_rows: int = 256,
+def axpy_op(a, x, y, block_rows: Optional[int] = None,
             interpret: Optional[bool] = None):
     interp = _on_cpu() if interpret is None else interpret
     return axpy(jnp.asarray(a, x.dtype), x, y, block_rows, interpret=interp)
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
-def dot_partials_op(x, y, block_rows: int = 256,
+def dot_partials_op(x, y, block_rows: Optional[int] = None,
                     interpret: Optional[bool] = None):
     interp = _on_cpu() if interpret is None else interpret
     return dot_partials(x, y, block_rows, interpret=interp)
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
-def dot_op(x, y, block_rows: int = 256, interpret: Optional[bool] = None):
+def dot_op(x, y, block_rows: Optional[int] = None,
+           interpret: Optional[bool] = None):
     """x·y via per-block partials folded in block order (bit-fixed)."""
     return fold_partials(dot_partials_op(x, y, block_rows=block_rows,
                                          interpret=interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
-def gemv_op(A, x, block_rows: int = 256,
+def gemv_op(A, x, block_rows: Optional[int] = None,
             interpret: Optional[bool] = None):
     interp = _on_cpu() if interpret is None else interpret
     return gemv(A, x, block_rows, interpret=interp)
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
-def axpydot_op(a, x, y, w, block_rows: int = 256,
+def axpydot_op(a, x, y, w, block_rows: Optional[int] = None,
                interpret: Optional[bool] = None):
     """(a*x + y)·w — the FpgaHbmForDaCe fused two-stage workload."""
     z = axpy_op(a, x, y, block_rows=block_rows, interpret=interpret)

@@ -51,14 +51,17 @@ def _knn_kernel(q_ref, x_ref, od_ref, oi_ref, best_d, best_i, *,
     cand_i = jnp.concatenate([best_i[...], gidx], axis=1)
     new_d = jnp.zeros((q.shape[0], k), jnp.float32)
     new_i = jnp.zeros((q.shape[0], k), jnp.int32)
+    # Column j of the result is written with a lane-iota select: Mosaic
+    # cannot lower the scatter that ``.at[:, j].set`` becomes.
+    out_lane = jax.lax.broadcasted_iota(jnp.int32, new_d.shape, 1)
+    cand_lane = jax.lax.broadcasted_iota(jnp.int32, cand_d.shape, 1)
     for j in range(k):
         m = jnp.min(cand_d, axis=1)                          # [BQ]
         am = jnp.argmin(cand_d, axis=1)                      # [BQ]
-        sel = (jax.lax.broadcasted_iota(jnp.int32, cand_d.shape, 1)
-               == am[:, None])
+        sel = cand_lane == am[:, None]
         mi = jnp.sum(jnp.where(sel, cand_i, 0), axis=1)
-        new_d = new_d.at[:, j].set(m)
-        new_i = new_i.at[:, j].set(mi)
+        new_d = jnp.where(out_lane == j, m[:, None], new_d)
+        new_i = jnp.where(out_lane == j, mi[:, None], new_i)
         cand_d = jnp.where(sel, BIG, cand_d)
     best_d[...] = new_d
     best_i[...] = new_i

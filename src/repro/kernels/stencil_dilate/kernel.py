@@ -2,22 +2,26 @@
 
 TPU adaptation of the paper's line-buffered FPGA dataflow PE: the FPGA
 version streams rows through BRAM line buffers; on TPU we tile rows into
-VMEM blocks of [BLOCK_ROWS, W] (W = full row so the 8×128 VPU lanes stream
-contiguous sublanes), with a 2-row halo realized by passing the same input
-under three BlockSpecs (prev/cur/next row-block) — Pallas blocks cannot
-overlap, so the halo is explicit.  Column shifts happen in-register.
+VMEM blocks of [block_rows, W] (W = full row so the 8×128 VPU lanes stream
+contiguous sublanes; block_rows is sized to scoped VMEM), with a 2-row
+halo realized by passing the same input under three BlockSpecs
+(prev/cur/next row-block) — Pallas blocks cannot overlap, so the halo is
+explicit.  Column shifts happen in-register.
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ..vmem import block_rows_for
 from .ref import OFFSETS
 
-DEFAULT_BLOCK_ROWS = 256
+# prev/cur/next input blocks and the output block, each double-buffered.
+RESIDENT_BLOCKS = 8
 
 
 def _dilate_kernel(prev_ref, cur_ref, next_ref, o_ref, *, block_rows: int):
@@ -49,12 +53,16 @@ def _dilate_kernel(prev_ref, cur_ref, next_ref, o_ref, *, block_rows: int):
     o_ref[...] = out
 
 
-def dilate(img: jax.Array, block_rows: int = DEFAULT_BLOCK_ROWS,
+def dilate(img: jax.Array, block_rows: Optional[int] = None,
            interpret: bool = False) -> jax.Array:
-    """One dilate iteration.  img: [H, W], H % block_rows == 0."""
+    """One dilate iteration.  img: [H, W].
+
+    ``block_rows`` None sizes the row blocks to scoped VMEM; a given size
+    must divide H and fit (see :func:`repro.kernels.vmem.block_rows_for`).
+    """
     H, W = img.shape
-    block_rows = min(block_rows, H)
-    assert H % block_rows == 0, (H, block_rows)
+    block_rows = block_rows_for(H, W * img.dtype.itemsize, RESIDENT_BLOCKS,
+                                block_rows)
     grid = (H // block_rows,)
     nblk = H // block_rows
 

@@ -16,7 +16,7 @@ def _on_cpu() -> bool:
 
 @functools.partial(jax.jit, static_argnames=("iters", "block_rows",
                                              "interpret"))
-def dilate_op(img, iters: int = 1, block_rows: int = 256,
+def dilate_op(img, iters: int = 1, block_rows: Optional[int] = None,
               interpret: Optional[bool] = None):
     interp = _on_cpu() if interpret is None else interpret
 

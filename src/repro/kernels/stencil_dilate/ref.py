@@ -5,6 +5,7 @@ Morphological dilation with a diamond structuring element of radius 2
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 OFFSETS = tuple((di, dj)
@@ -25,6 +26,4 @@ def dilate_ref(img: jnp.ndarray) -> jnp.ndarray:
 
 
 def dilate_iters_ref(img: jnp.ndarray, iters: int) -> jnp.ndarray:
-    for _ in range(iters):
-        img = dilate_ref(img)
-    return img
+    return jax.lax.fori_loop(0, iters, lambda _, x: dilate_ref(x), img)

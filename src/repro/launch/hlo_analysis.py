@@ -189,9 +189,6 @@ def cpu_bf16_convert_bytes(hlo_text: str) -> float:
 
 def cost_summary(compiled) -> Dict[str, float]:
     ca = compiled.cost_analysis() or {}
-    # jax 0.4.3x wraps the per-program dict in a one-element list.
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
     out = {"flops": float(ca.get("flops", 0.0)),
            "bytes_accessed": float(ca.get("bytes accessed", 0.0))}
     for k, v in ca.items():

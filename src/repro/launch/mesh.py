@@ -9,6 +9,7 @@ from __future__ import annotations
 from typing import Optional
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -16,9 +17,14 @@ def make_production_mesh(*, multi_pod: bool = False):
     leading 'pod' axis (DCN-connected)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape, axes):
-    """Arbitrary mesh (tests use small fake meshes, e.g. (2,2,2))."""
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    """Arbitrary mesh (tests use small fake meshes, e.g. (2,2,2)).
+
+    Every axis is ``Auto``: the model code places activations with
+    ``with_sharding_constraint`` and lets GSPMD propagate, which explicit
+    axes (``jax.make_mesh``'s default) reject."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))

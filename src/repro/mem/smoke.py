@@ -38,6 +38,7 @@ def main() -> int:
     args = ap.parse_args()
 
     import jax
+
     import jax.numpy as jnp
 
     from ..apps import APPS
@@ -46,6 +47,8 @@ def main() -> int:
     from ..exec import bind_programs, execute
     from ..obs.trace import Tracer, write_chrome_trace
     from .banks import MemConfig
+    from ..runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     print(f"devices: {jax.devices()}")
     cluster = fpga_ring_cluster(args.ndev)

@@ -5,7 +5,7 @@ Compiles the stencil app onto a 2×2 mesh cluster with an explicit fabric
 fabric and on the ideal transfer path — and asserts:
 
 * numerics are **bit-identical** between the two paths (and match the
-  single-device Pallas reference within the binding's atol);
+  single-device reference within the binding's atol);
 * the fabric accounting conserves bytes (every submitted byte delivered;
   per-link totals sum exactly to the hop-weighted cut-set traffic);
 * the λ route costing reproduces the partitioner's Eq. 2 objective.
@@ -38,6 +38,7 @@ def main() -> int:
     args = ap.parse_args()
 
     import jax
+
     import jax.numpy as jnp
 
     from ..apps import APPS
@@ -46,6 +47,8 @@ def main() -> int:
     from ..exec import bind_programs, execute
     from ..obs.trace import Tracer, write_chrome_trace
     from . import cluster_fabric
+    from ..runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     ndev = args.rows * args.cols
     print(f"devices: {jax.devices()}")

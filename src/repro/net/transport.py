@@ -515,8 +515,10 @@ class FabricTransport:
     def step(self, sweep: int) -> List[Tuple[int, int]]:
         """Arbitrate every link for one sweep.
 
-        Returns ``[(message_id, channel_index)]`` for messages whose final
-        flit was delivered this sweep (completion order is deterministic).
+        Returns ``[(message_id, channel_index)]`` for messages completed
+        this sweep: every flit delivered, and every older message of the
+        same (flow, channel) reported before (completion order is
+        deterministic).
         """
         self.sweeps_run += 1
         self._land_transit(sweep)
@@ -586,10 +588,18 @@ class FabricTransport:
             if epoch == m.epoch and m.mid in self._messages:
                 m.at_hop[hop] += 1
         self._inject()
-        completed = [(m.mid, m.channel_index)
-                     for m in sorted(self._messages.values(),
-                                     key=lambda m: m.mid)
-                     if m.done() and m.delivered_sweep == sweep]
+        # A channel's messages complete in submission order: round-robin
+        # arbitration lets a short message overtake a longer one of the
+        # same channel, so a finished message waits behind every older
+        # one of its (flow, channel) still in the network.
+        completed = []
+        waiting = set()
+        for m in sorted(self._messages.values(), key=lambda m: m.mid):
+            key = (m.flow, m.channel_index)
+            if m.done() and key not in waiting:
+                completed.append((m.mid, m.channel_index))
+            else:
+                waiting.add(key)
         for mid, _ in completed:
             del self._messages[mid]
         return completed

@@ -82,6 +82,8 @@ def main() -> int:
     from .metrics import (assert_registry_consistent,
                           assert_trace_report_consistent, from_report)
     from .trace import Tracer, write_chrome_trace
+    from ..runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     rows = []
     app_records = {}

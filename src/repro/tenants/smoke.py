@@ -54,6 +54,7 @@ def main() -> int:
     args = ap.parse_args()
 
     import jax
+
     import jax.numpy as jnp
 
     from ..apps import APPS
@@ -68,6 +69,8 @@ def main() -> int:
     from ..obs.trace import Tracer, write_chrome_trace
     from . import (SLO, DeviceKill, Tenant, TenantServer, bit_identical,
                    isolation_check)
+    from ..runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     print(f"devices: {jax.devices()}")
     shared = fpga_ring_cluster(4)

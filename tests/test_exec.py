@@ -7,6 +7,7 @@ exactly the channels the partitioner's Eq. 2 objective charged.
 Regression: a FIFO clamped below its §4.6 balanced depth is caught by the
 starvation detector, while the compiler's balanced depths run clean.
 """
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -154,6 +155,44 @@ def test_execute_entry_point_on_artifact():
     result = design.execute(inputs={"h": 32, "w": 32, "streams": 2})
     assert result.outputs.shape == (2, 32, 32)
     assert result.report.iterations == 2
+
+
+def test_stencil_stages_run_the_kernel(monkeypatch):
+    """Stage bodies run the Pallas kernel; ``reference()`` is plain jnp,
+    and the two agree bit for bit."""
+    import repro.kernels as kernels
+    calls = []
+    real = kernels.dilate_op
+
+    def spy(img, **kw):
+        calls.append(kw)
+        return real(img, **kw)
+
+    monkeypatch.setattr(kernels, "dilate_op", spy)
+    design = _compile("stencil", 2)
+    binding = bind_programs(design.graph, {"h": 32, "w": 128,
+                                           "stage_iters": 3, "streams": 2})
+    assert binding.atol == 0.0
+    result = execute(design, binding)
+    assert [c["iters"] for c in calls] == [3] * 4    # 2 stages x 2 images
+    np.testing.assert_array_equal(np.asarray(result.outputs),
+                                  np.asarray(binding.reference()))
+    assert len(calls) == 4                           # reference: no kernel
+
+
+def test_placement_recorded_and_checked():
+    design = _compile("stencil", 2)
+    dev = jax.devices()[0]
+    name = f"{dev.platform}:{dev.id}"
+    report = execute(design, device_map=[0, 0]).report
+    assert report.placement == {0: name, 1: name}
+    summary = report.summary()
+    assert summary["placement"] == {"0": name, "1": name}
+    assert summary["task_devices"] == {t: [name]
+                                       for t in design.graph.tasks}
+    # A map naming a device the pool lacks is refused, not wrapped.
+    with pytest.raises(ValueError, match="only 2 devices"):
+        execute(design, devices=[dev, dev], device_map=[0, 2])
 
 
 def test_bind_programs_rejects_unknown_graph():

@@ -32,6 +32,12 @@ def random_instance(data, min_nodes=4, max_nodes=40):
                          for _ in range(nk)]) for n in nodes}
     # Loose enough that refinement has room, tight enough to bind sometimes.
     caps = np.full((ndev, nk), float(nv * 8 // ndev + 10))
+    # The refiner keeps a feasible start feasible; it does not repair an
+    # infeasible one, so raise a device's cap to what the start put there.
+    start = np.zeros((ndev, nk))
+    for n, d in assign.items():
+        start[d] += area[n]
+    caps = np.maximum(caps, start)
     return assign, edges, ring_pair_cost(ndev), area, caps, ndev, nk
 
 

@@ -110,8 +110,6 @@ SUBPROC_SCRIPT = textwrap.dedent("""
         }
         lowered = steps.lower_train(cfg, mesh, batch, microbatches=2)
         compiled = lowered.compile()
-        # cost_summary normalizes the jax 0.4.3x one-element-list return of
-        # compiled.cost_analysis() (a raw .get() here broke on that version).
         ca = hlo_analysis.cost_summary(compiled)
         out[name] = {"flops": ca["flops"], "ok": True}
     print(json.dumps(out))

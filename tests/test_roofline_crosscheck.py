@@ -31,7 +31,6 @@ def _forward_flops(cfg, batch, seq):
     compiled = jax.jit(fwd).lower(
         jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg)),
         toks).compile()
-    # cost_summary normalizes the jax 0.4.3x one-element-list return shape.
     return cost_summary(compiled)["flops"]
 
 
