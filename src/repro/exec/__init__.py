@@ -20,6 +20,30 @@ CI needs no accelerator: ``XLA_FLAGS=--xla_force_host_platform_device_count=N``
 provides the device mesh (see ``python -m repro.exec.smoke``), and a bare
 single-device interpreter still executes every design — logical placement
 keeps driving the traffic accounting.
+
+Profiler spans (``jax.profiler.TraceAnnotation``, on the caller's thread,
+recorded only while a ``jax.profiler`` trace is running):
+
+==================  =====================  ================================
+span                args                   holds
+==================  =====================  ================================
+``exec.execute``    ``graph``, ``call``    one ``execute()``; ``call`` counts
+                                           the calls of this process
+``exec.state``                             building the ``ExecutionState``:
+                                           channels, memory streams, priming
+``exec.sweep``      ``sweep``              one ``ExecutionState.advance``
+``exec.fire``       ``task``, ``device``   one firing: pop, placement,
+                                           dispatch, block, push
+``exec.dispatch``                          the task's program call (JAX's
+                                           own spans nest inside)
+``exec.block``                             the host waiting on the firing's
+                                           outputs
+``exec.xfer``       ``channel``,           a token's move to its consumer's
+                    ``nbytes``             device (inter-device channels)
+``exec.finalize``                          the binding's output assembly
+``exec.report``                            the ``ExecutionReport`` and its
+                                           Eq. 2 accounting
+==================  =====================  ================================
 """
 from .channels import ChannelStats, FifoChannel, token_bytes
 from .executor import (DeadlockError, ExecutionResult, ExecutionState,

@@ -79,7 +79,8 @@ class ChannelStats:
 
 
 class _Entry:
-    """One queued token: visibility sweep (None while in the network)."""
+    """One queued token: visibility sweep (None while in the network) and,
+    on an inter-device channel, payload bytes."""
 
     __slots__ = ("vis", "token", "mid", "nbytes")
 
@@ -176,11 +177,15 @@ class FifoChannel:
         if self.full:
             raise ValueError(f"channel {self.src}->{self.dst}: "
                              "cannot prime a full FIFO")
+        nbytes = 0
         if self.inter_device:
-            self.stats.measured_bytes += token_bytes(token)
+            nbytes = token_bytes(token)
+            self.stats.measured_bytes += nbytes
             if self.eager_transfer or self.transport is not None:
-                token = _put(token, self.dst_device)
-        self._q.append(_Entry(0, token))
+                with jax.profiler.TraceAnnotation(
+                        "exec.xfer", channel=self.index, nbytes=nbytes):
+                    token = _put(token, self.dst_device)
+        self._q.append(_Entry(0, token, None, nbytes))
         self.stats.tokens += 1
         self.stats.max_occupancy = max(self.stats.max_occupancy, len(self._q))
 
@@ -188,6 +193,7 @@ class FifoChannel:
         if self.full:
             self.stats.blocked_pushes += 1
             raise RuntimeError(f"push on full channel {self.src}->{self.dst}")
+        nbytes = 0
         if self.inter_device:
             nbytes = token_bytes(token)
             self.stats.measured_bytes += nbytes
@@ -206,8 +212,10 @@ class FifoChannel:
                                                len(self._q))
                 return
             if self.eager_transfer:
-                token = _put(token, self.dst_device)
-        self._q.append(_Entry(sweep + self.latency, token))
+                with jax.profiler.TraceAnnotation(
+                        "exec.xfer", channel=self.index, nbytes=nbytes):
+                    token = _put(token, self.dst_device)
+        self._q.append(_Entry(sweep + self.latency, token, None, nbytes))
         self.stats.tokens += 1
         self.stats.max_occupancy = max(self.stats.max_occupancy, len(self._q))
 
@@ -215,7 +223,9 @@ class FifoChannel:
         """The fabric delivered this token's final flit: place the payload
         on the destination device and open its visibility next sweep."""
         entry = self._pending.pop(mid)
-        entry.token = _put(entry.token, self.dst_device)
+        with jax.profiler.TraceAnnotation(
+                "exec.xfer", channel=self.index, nbytes=entry.nbytes):
+            entry.token = _put(entry.token, self.dst_device)
         entry.vis = sweep + 1
         self.stats.net_delivered_bytes += entry.nbytes
 
@@ -231,7 +241,9 @@ class FifoChannel:
                                     self.trace_flow)
         if (self.inter_device and self.transport is None
                 and not self.eager_transfer):
-            token = _put(token, self.dst_device)
+            with jax.profiler.TraceAnnotation(
+                    "exec.xfer", channel=self.index, nbytes=entry.nbytes):
+                token = _put(token, self.dst_device)
         return token
 
     def pending_visibility(self) -> List[int]:

@@ -73,6 +73,7 @@ Detection:
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import time
 from typing import Any, Dict, List, Mapping, Optional, Sequence
 
@@ -93,6 +94,10 @@ class DeadlockError(RuntimeError):
 class StarvationError(DeadlockError):
     """A join repeatedly starves behind an unbalanced FIFO (§4.6)."""
 
+
+#: Request ids of ``execute()`` calls in this process (the ``call`` argument
+#: of the ``exec.execute`` span).
+_CALLS = itertools.count()
 
 #: Sentinel for ``execute(fabric=...)``: use the design's fabric (pass
 #: ``fabric=None`` explicitly to force the ideal transfer path).
@@ -344,7 +349,6 @@ class ExecutionState:
         self.congestion_waits: Dict[str, int] = {}
         self.mem_waits: Dict[str, int] = {}
         self.sink_outputs: Dict[str, List[Any]] = {t: [] for t in self.sinks}
-        self.busy_s: Dict[int, float] = {}
         self.dev_fired: Dict[int, int] = {}
         # Devices each task's output arrays were found on (measured).
         self.task_devices: Dict[str, set] = {t: set() for t in graph.tasks}
@@ -415,6 +419,10 @@ class ExecutionState:
         """Fire every ready task once (reverse topo order); returns the
         firing count.  Does NOT step the transport / memory system — the
         owner of those does (``run()`` solo, the tenant server shared)."""
+        with jax.profiler.TraceAnnotation("exec.sweep", sweep=sweep):
+            return self._advance(sweep)
+
+    def _advance(self, sweep: int) -> int:
         binding, T = self.binding, self.iterations
         tr, flow = self.tracer, self.trace_flow
         fired_this_sweep = 0
@@ -503,35 +511,35 @@ class ExecutionState:
                     tr.task_wait(sweep, v, self.assign[v], "mem", flow)
                 continue
             dev = self.assign[v]
-            token_in: Dict[str, Any] = {fc.src: fc.pop(sweep)
-                                        for fc in in_chs}
-            # Stream items and memory responses are placed on the firing
-            # task's device, so the program runs there.
-            if not in_chs and v in binding.source_inputs:
-                token_in[SOURCE_KEY] = _put(
-                    binding.source_inputs[v][self.fired[v]],
-                    self.jax_dev[dev])
-            for mc in self.mem_chs[v]:
-                token_in[mc.stream] = _put(mc.consume(sweep),
-                                           self.jax_dev[dev])
-            t0 = time.perf_counter()
-            out = binding.programs[v](token_in)
-            _block(out)
-            busy = time.perf_counter() - t0
-            self.task_devices[v].update(_device_names(out))
-            self.busy_s[dev] = self.busy_s.get(dev, 0.0) + busy
-            self.dev_fired[dev] = self.dev_fired.get(dev, 0) + 1
-            if tr.enabled:
-                tr.task_fire(sweep, v, dev, busy, flow)
-            if isinstance(out, RoutedOutput):
-                for fc in out_chs:
-                    fc.push(out[fc.dst], sweep)
-            else:
-                for fc in out_chs:
-                    fc.push(out, sweep)
-            if v in self.sinks:
-                self.sink_outputs[v].append(out)
-            self.fired[v] += 1
+            with jax.profiler.TraceAnnotation("exec.fire", task=v, device=dev):
+                token_in: Dict[str, Any] = {fc.src: fc.pop(sweep)
+                                            for fc in in_chs}
+                # Stream items and memory responses are placed on the firing
+                # task's device, so the program runs there.
+                if not in_chs and v in binding.source_inputs:
+                    token_in[SOURCE_KEY] = _put(
+                        binding.source_inputs[v][self.fired[v]],
+                        self.jax_dev[dev])
+                for mc in self.mem_chs[v]:
+                    token_in[mc.stream] = _put(mc.consume(sweep),
+                                               self.jax_dev[dev])
+                with jax.profiler.TraceAnnotation("exec.dispatch"):
+                    out = binding.programs[v](token_in)
+                with jax.profiler.TraceAnnotation("exec.block"):
+                    _block(out)
+                self.task_devices[v].update(_device_names(out))
+                self.dev_fired[dev] = self.dev_fired.get(dev, 0) + 1
+                if tr.enabled:
+                    tr.task_fire(sweep, v, dev, flow)
+                if isinstance(out, RoutedOutput):
+                    for fc in out_chs:
+                        fc.push(out[fc.dst], sweep)
+                else:
+                    for fc in out_chs:
+                        fc.push(out, sweep)
+                if v in self.sinks:
+                    self.sink_outputs[v].append(out)
+                self.fired[v] += 1
             fired_this_sweep += 1
         self.sweeps_done = max(self.sweeps_done, sweep + 1)
         return fired_this_sweep
@@ -540,23 +548,24 @@ class ExecutionState:
     def build_result(self, sweeps: int, wall_time_s: float
                      ) -> ExecutionResult:
         """Fold the state into the measured report + finalized outputs."""
-        report = build_report(
-            design=self.design, channels=self.channels,
-            iterations=self.iterations, sweeps=sweeps,
-            wall_time_s=wall_time_s, device_busy_s=self.busy_s,
-            device_fired=self.dev_fired,
-            starvation_events=self.starve_events,
-            starvation_detail=self.starve_detail, transport=self.transport,
-            congestion_waits=self.congestion_waits, memsys=self.memsys,
-            mem_channels=self.mem_channels, mem_waits=self.mem_waits,
-            tracer=self.tracer,
-            placement={d: device_name(jd)
-                       for d, jd in enumerate(self.jax_dev)},
-            task_devices={t: sorted(n)
-                          for t, n in self.task_devices.items()})
-        outputs = (self.binding.finalize(self.sink_outputs)
-                   if self.binding.finalize is not None
-                   else self.sink_outputs)
+        with jax.profiler.TraceAnnotation("exec.report"):
+            report = build_report(
+                design=self.design, channels=self.channels,
+                iterations=self.iterations, sweeps=sweeps,
+                wall_time_s=wall_time_s, device_fired=self.dev_fired,
+                starvation_events=self.starve_events,
+                starvation_detail=self.starve_detail, transport=self.transport,
+                congestion_waits=self.congestion_waits, memsys=self.memsys,
+                mem_channels=self.mem_channels, mem_waits=self.mem_waits,
+                tracer=self.tracer,
+                placement={d: device_name(jd)
+                           for d, jd in enumerate(self.jax_dev)},
+                task_devices={t: sorted(n)
+                              for t, n in self.task_devices.items()})
+        outputs = self.sink_outputs
+        if self.binding.finalize is not None:
+            with jax.profiler.TraceAnnotation("exec.finalize"):
+                outputs = self.binding.finalize(self.sink_outputs)
         return ExecutionResult(outputs=outputs,
                                sink_outputs=self.sink_outputs,
                                report=report)
@@ -678,11 +687,14 @@ def execute(design: CompiledDesign,
     from every layer (``None`` → the zero-overhead ``NULL_TRACER``); a
     recording tracer is attached to the result as ``report.trace``.
     """
-    return ExecutionState(
-        design, binding, inputs=inputs, devices=devices,
-        max_sweeps=max_sweeps, starve_limit=starve_limit,
-        check_starvation=check_starvation, fabric=fabric,
-        net_config=net_config, mem=mem, faults=faults,
-        tracer=tracer, device_map=device_map).run(
-            injector=injector, checkpoint_dir=checkpoint_dir,
-            checkpoint_every=checkpoint_every)
+    with jax.profiler.TraceAnnotation("exec.execute", graph=design.graph.name,
+                                      call=next(_CALLS)):
+        with jax.profiler.TraceAnnotation("exec.state"):
+            state = ExecutionState(
+                design, binding, inputs=inputs, devices=devices,
+                max_sweeps=max_sweeps, starve_limit=starve_limit,
+                check_starvation=check_starvation, fabric=fabric,
+                net_config=net_config, mem=mem, faults=faults,
+                tracer=tracer, device_map=device_map)
+        return state.run(injector=injector, checkpoint_dir=checkpoint_dir,
+                         checkpoint_every=checkpoint_every)

@@ -127,7 +127,6 @@ class ExecutionReport:
     sweeps: int
     wall_time_s: float
     channels: List[ChannelTrace]
-    device_busy_s: Dict[int, float]
     device_fired: Dict[int, int]
     starvation_events: Dict[str, int]
     starvation_detail: List[Dict[str, Any]]
@@ -228,12 +227,6 @@ class ExecutionReport:
         return (self.congestion.total_bytes
                 if self.congestion is not None else 0.0)
 
-    def device_busy_frac(self) -> Dict[int, float]:
-        if self.wall_time_s <= 0:
-            return {d: 0.0 for d in self.device_busy_s}
-        return {d: b / self.wall_time_s
-                for d, b in sorted(self.device_busy_s.items())}
-
     def agreement(self) -> Dict[str, bool]:
         """The measured-vs-predicted accounting checks (see module doc)."""
         out = {
@@ -274,8 +267,6 @@ class ExecutionReport:
             "iterations": self.iterations,
             "sweeps": self.sweeps,
             "wall_time_s": round(self.wall_time_s, 4),
-            "device_busy_s": {str(d): round(b, 4)
-                              for d, b in sorted(self.device_busy_s.items())},
             "device_fired": {str(d): n
                              for d, n in sorted(self.device_fired.items())},
             "starvation_events": dict(self.starvation_events),
@@ -327,7 +318,6 @@ class ExecutionReport:
 
 def build_report(*, design, channels: Sequence[FifoChannel],
                  iterations: int, sweeps: int, wall_time_s: float,
-                 device_busy_s: Mapping[int, float],
                  device_fired: Mapping[int, int],
                  starvation_events: Mapping[str, int],
                  starvation_detail: Sequence[Dict[str, Any]],
@@ -418,7 +408,6 @@ def build_report(*, design, channels: Sequence[FifoChannel],
         sweeps=sweeps,
         wall_time_s=wall_time_s,
         channels=traces,
-        device_busy_s=dict(device_busy_s),
         device_fired=dict(device_fired),
         starvation_events=dict(starvation_events),
         starvation_detail=list(starvation_detail),

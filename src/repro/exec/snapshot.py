@@ -101,7 +101,6 @@ def save_snapshot(state: ExecutionState, sweep: int, directory: str) -> str:
                          for t, outs in state.sink_outputs.items()},
         "channels": channels,
         "mem_channels": mem,
-        "busy_s": dict(state.busy_s),
         "dev_fired": dict(state.dev_fired),
         "starve_events": dict(state.starve_events),
         "starve_detail": list(state.starve_detail),
@@ -167,7 +166,6 @@ def restore_state(state: ExecutionState, payload: Dict[str, Any]) -> None:
     state.fired = dict(payload["fired"])
     state.sink_outputs = {t: list(outs) for t, outs
                           in payload["sink_outputs"].items()}
-    state.busy_s = dict(payload["busy_s"])
     state.dev_fired = dict(payload["dev_fired"])
     state.starve_events = dict(payload["starve_events"])
     state.starve_detail = list(payload["starve_detail"])

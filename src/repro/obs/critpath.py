@@ -157,7 +157,7 @@ def analyze(tracer: Tracer, *, sweeps: int) -> CritPath:
     for e in tracer.events:
         kind = e[0]
         if kind == "task_fire":
-            _, sweep, task, device, _busy, flow = e
+            _, sweep, task, device, flow = e
             rec = fired.setdefault((flow, task), [device, 0, {}, set()])
             rec[0] = device
             rec[1] += 1

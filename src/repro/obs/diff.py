@@ -21,7 +21,7 @@ Baseline document format (``obs-baseline/v1``)::
     {"format": "obs-baseline/v1",
      "default_rel_tol": 0.0,
      "tolerances": {"net.link.utilization": 0.05},   # per-metric rel tol
-     "ignore": ["exec.device.busy_s"],               # nondeterministic
+     "ignore": [],                                   # nondeterministic
      "apps": {"stencil": { ...registry.to_json()... }, ...}}
 
 A flat single-registry baseline (``"metrics"`` instead of ``"apps"``) is

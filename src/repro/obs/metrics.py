@@ -12,7 +12,7 @@ prefix                series
 ====================  =====================================================
 ``exec.task.*``       ``congestion_waits``, ``mem_waits``,
                       ``starvation_events`` — labeled ``task=``
-``exec.device.*``     ``fired`` (counter), ``busy_s`` (gauge) — ``device=``
+``exec.device.*``     ``fired`` (counter) — ``device=``
 ``exec.channel.*``    ``tokens``, ``bytes``, ``net_bytes``,
                       ``max_occupancy`` — ``channel=`` (inter-device only)
 ``net.link.*``        ``goodput_bytes``, ``flits``, ``retransmit_bytes``,
@@ -146,8 +146,6 @@ def from_report(report) -> MetricsRegistry:
         reg.counter_add("exec.task.starvation_events", n, task=task)
     for dev, n in report.device_fired.items():
         reg.counter_add("exec.device.fired", n, device=dev)
-    for dev, s in report.device_busy_s.items():
-        reg.gauge_set("exec.device.busy_s", s, device=dev)
     for c in report.channels:
         if not c.inter_device:
             continue

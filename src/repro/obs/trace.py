@@ -41,7 +41,7 @@ from typing import Any, Dict, List, Optional, Tuple
 #: ``upstream`` (input empty, nothing in flight — a dataflow dependency),
 #: ``backpressure`` (inputs ready but an output FIFO is full).
 EVENT_FIELDS: Dict[str, Tuple[str, ...]] = {
-    "task_fire": ("task", "device", "busy_s", "flow"),
+    "task_fire": ("task", "device", "flow"),
     "task_wait": ("task", "device", "reason", "flow"),
     "channel_push": ("channel", "src", "dst", "nbytes", "flow"),
     "channel_pop": ("channel", "src", "dst", "flow"),
@@ -91,8 +91,8 @@ class Tracer:
 
     # -- exec ----------------------------------------------------------------
     def task_fire(self, sweep: int, task: str, device: int,
-                  busy_s: float, flow: int = 0) -> None:
-        self.events.append(("task_fire", sweep, task, device, busy_s, flow))
+                  flow: int = 0) -> None:
+        self.events.append(("task_fire", sweep, task, device, flow))
 
     def task_wait(self, sweep: int, task: str, device: int,
                   reason: str, flow: int = 0) -> None:
@@ -322,12 +322,12 @@ def to_chrome_trace(tracer: Tracer, *,
     for e in tracer.events:
         kind, sweep = e[0], e[1]
         if kind == "task_fire":
-            task, device, busy_s, flow = e[2:]
+            task, device, flow = e[2:]
             pid, tid = tids.tid(device, f"task:{task}")
             events.append({
                 "ph": "X", "name": task, "cat": "exec", "pid": pid,
                 "tid": tid, "ts": ts(sweep), "dur": u,
-                "args": {"busy_s": busy_s, "flow": flow}})
+                "args": {"flow": flow}})
         elif kind == "task_wait":
             task, device, reason, flow = e[2:]
             pid, tid = tids.tid(device, f"task:{task}")

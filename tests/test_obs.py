@@ -55,7 +55,7 @@ def test_null_tracer_is_the_disabled_default():
     t = Tracer()
     assert coerce_tracer(t) is t
     # Every typed emit on the null tracer is a no-op.
-    NULL_TRACER.task_fire(0, "t", 0, 0.0, 0)
+    NULL_TRACER.task_fire(0, "t", 0, 0)
     NULL_TRACER.flit_hop(0, 0, 64, 0, 0)
     NULL_TRACER.bank_burst(0, 0, 0, 64, 0, 0)
     assert len(NULL_TRACER) == 0
@@ -65,7 +65,7 @@ def test_null_tracer_is_the_disabled_default():
 
 def test_typed_emits_match_their_schemas():
     t = Tracer()
-    t.task_fire(3, "stage0", 1, 0.5, 0)
+    t.task_fire(3, "stage0", 1, 0)
     t.task_wait(4, "stage1", 0, "net", 0)
     t.channel_push(5, 0, "a", "b", 128, 0)
     t.flit_hop(6, 2, 64, 0, 9)
