@@ -33,11 +33,13 @@ span                args                   holds
                                            channels, memory streams, priming
 ``exec.sweep``      ``sweep``              one ``ExecutionState.advance``
 ``exec.fire``       ``task``, ``device``   one firing: pop, placement,
-                                           dispatch, block, push
-``exec.dispatch``                          the task's program call (JAX's
-                                           own spans nest inside)
-``exec.block``                             the host waiting on the firing's
-                                           outputs
+                                           dispatch, push
+``exec.dispatch``   ``queued``             the task's program call (JAX's
+                                           own spans nest inside); ``queued``
+                                           is 1 where the device was still
+                                           computing its previous firing
+``exec.block``                             the host waiting once per run on
+                                           the sinks' outputs
 ``exec.xfer``       ``channel``,           a token's move to its consumer's
                     ``nbytes``             device (inter-device channels)
 ``exec.finalize``                          the binding's output assembly

@@ -17,6 +17,11 @@ Execution model (synchronous dataflow, one sweep ≈ one pipeline clock):
   (full throughput); clamp a depth below ``added + slack + 1`` and the
   reconvergent join starves — which the detector below reports instead of
   silently throttling.
+* The host never waits on a firing: JAX queues each program call on its
+  device, and the executor pops, places and pushes arrays not yet
+  computed, so each device keeps a queue of firings and the devices of a
+  chain run their stages at the same time.  A run waits once, on the
+  sinks' outputs, before it reads its wall time (:meth:`ExecutionState.block`).
 
 Network fabric (``repro.net``): when the design (or the caller) supplies a
 :class:`~repro.net.fabric.Fabric`, inter-device pushes are packetized into
@@ -117,6 +122,15 @@ def _block(token: Any) -> None:
     for leaf in jax.tree_util.tree_leaves(token):
         if hasattr(leaf, "block_until_ready"):
             leaf.block_until_ready()
+
+
+def _ready(token: Any) -> bool:
+    """Every array of ``token`` has been computed (asks, never waits).  An
+    array a later program took by donation is deleted, and was computed:
+    ``is_ready()`` must not be asked of it."""
+    return all(leaf.is_deleted() or leaf.is_ready()
+               for leaf in jax.tree_util.tree_leaves(token)
+               if isinstance(leaf, jax.Array))
 
 
 def device_name(device) -> str:
@@ -352,6 +366,10 @@ class ExecutionState:
         self.dev_fired: Dict[int, int] = {}
         # Devices each task's output arrays were found on (measured).
         self.task_devices: Dict[str, set] = {t: set() for t in graph.tasks}
+        # Firings dispatched while the previous firing's output on the same
+        # jax device was still being computed: the device had work queued.
+        self.queued_firings = 0
+        self._last_out: Dict[Any, Any] = {}
         self.sweeps_done = 0
 
     # -- progress queries ----------------------------------------------------
@@ -511,6 +529,7 @@ class ExecutionState:
                     tr.task_wait(sweep, v, self.assign[v], "mem", flow)
                 continue
             dev = self.assign[v]
+            jdev = self.jax_dev[dev]
             with jax.profiler.TraceAnnotation("exec.fire", task=v, device=dev):
                 token_in: Dict[str, Any] = {fc.src: fc.pop(sweep)
                                             for fc in in_chs}
@@ -518,15 +537,18 @@ class ExecutionState:
                 # task's device, so the program runs there.
                 if not in_chs and v in binding.source_inputs:
                     token_in[SOURCE_KEY] = _put(
-                        binding.source_inputs[v][self.fired[v]],
-                        self.jax_dev[dev])
+                        binding.source_inputs[v][self.fired[v]], jdev)
                 for mc in self.mem_chs[v]:
-                    token_in[mc.stream] = _put(mc.consume(sweep),
-                                               self.jax_dev[dev])
-                with jax.profiler.TraceAnnotation("exec.dispatch"):
+                    token_in[mc.stream] = _put(mc.consume(sweep), jdev)
+                # No wait on ``out``: JAX queues the program on its device,
+                # and the pushes below move arrays not yet computed.
+                prev = self._last_out.get(jdev)
+                queued = int(prev is not None and not _ready(prev))
+                self.queued_firings += queued
+                with jax.profiler.TraceAnnotation("exec.dispatch",
+                                                  queued=queued):
                     out = binding.programs[v](token_in)
-                with jax.profiler.TraceAnnotation("exec.block"):
-                    _block(out)
+                self._last_out[jdev] = out
                 self.task_devices[v].update(_device_names(out))
                 self.dev_fired[dev] = self.dev_fired.get(dev, 0) + 1
                 if tr.enabled:
@@ -545,6 +567,12 @@ class ExecutionState:
         return fired_this_sweep
 
     # -- wrap-up -------------------------------------------------------------
+    def block(self) -> None:
+        """Wait until the sinks' outputs are computed: the one place a run
+        waits on the device (``run()`` solo, the tenant server shared)."""
+        with jax.profiler.TraceAnnotation("exec.block"):
+            _block(self.sink_outputs)
+
     def build_result(self, sweeps: int, wall_time_s: float
                      ) -> ExecutionResult:
         """Fold the state into the measured report + finalized outputs."""
@@ -553,6 +581,7 @@ class ExecutionState:
                 design=self.design, channels=self.channels,
                 iterations=self.iterations, sweeps=sweeps,
                 wall_time_s=wall_time_s, device_fired=self.dev_fired,
+                queued_firings=self.queued_firings,
                 starvation_events=self.starve_events,
                 starvation_detail=self.starve_detail, transport=self.transport,
                 congestion_waits=self.congestion_waits, memsys=self.memsys,
@@ -638,6 +667,7 @@ class ExecutionState:
             for rid, ch_index in memsys.drain(sweep + 1):
                 self.mem_deliver(ch_index, rid, sweep)
 
+        self.block()
         wall = time.perf_counter() - t_start
         return self.build_result(sweep + 1, wall)
 

@@ -161,6 +161,10 @@ class ExecutionReport:
     placement: Dict[int, str] = dataclasses.field(default_factory=dict)
     task_devices: Dict[str, List[str]] = dataclasses.field(
         default_factory=dict)
+    # Firings dispatched while the previous firing's output on the same
+    # jax device was not yet computed, so the device had work queued ahead
+    # (at most firings - 1; 0 where every firing found its device idle).
+    queued_firings: int = 0
 
     # One-release deprecation shims for the pre-registry counter names.
     congestion_waits = _deprecated_field(
@@ -267,6 +271,7 @@ class ExecutionReport:
             "iterations": self.iterations,
             "sweeps": self.sweeps,
             "wall_time_s": round(self.wall_time_s, 4),
+            "queued_firings": self.queued_firings,
             "device_fired": {str(d): n
                              for d, n in sorted(self.device_fired.items())},
             "starvation_events": dict(self.starvation_events),
@@ -328,8 +333,8 @@ def build_report(*, design, channels: Sequence[FifoChannel],
                  mem_waits: Optional[Mapping[str, int]] = None,
                  tracer=None,
                  placement: Optional[Mapping[int, str]] = None,
-                 task_devices: Optional[Mapping[str, List[str]]] = None
-                 ) -> ExecutionReport:
+                 task_devices: Optional[Mapping[str, List[str]]] = None,
+                 queued_firings: int = 0) -> ExecutionReport:
     """Assemble the report from live channels + the design's analytics."""
     part, cluster = design.partition, design.cluster
     fabric = transport.fabric if transport is not None else None
@@ -427,4 +432,5 @@ def build_report(*, design, channels: Sequence[FifoChannel],
         task_mem_waits=dict(mem_waits or {}),
         trace=tracer if getattr(tracer, "enabled", False) else None,
         placement=dict(placement or {}),
-        task_devices=dict(task_devices or {}))
+        task_devices=dict(task_devices or {}),
+        queued_firings=queued_firings)

@@ -459,11 +459,14 @@ class TenantServer:
                 if rec.state is not None:
                     rec.state.mem_deliver(local, rid, sweep)
 
+        done = [r for r in self.records
+                if r.status == "done" and r.state is not None]
+        for rec in done:
+            rec.state.block()
         wall = time.perf_counter() - t_start
-        for rec in self.records:
-            if rec.status == "done" and rec.state is not None:
-                rec.result = rec.state.build_result(
-                    (rec.end_sweep or sweep) + 1 - rec.start_sweep, wall)
+        for rec in done:
+            rec.result = rec.state.build_result(
+                (rec.end_sweep or sweep) + 1 - rec.start_sweep, wall)
         return ServeOutcome(records=self.records, sweeps=sweep + 1,
                             wall_time_s=wall,
                             conservation=self.conservation())

@@ -67,7 +67,7 @@ def test_span_counts(traced):
     assert counts == {
         "exec.execute": 1, "exec.state": 1, "exec.sweep": report.sweeps,
         "exec.fire": firings, "exec.dispatch": firings,
-        "exec.block": firings, "exec.xfer": moves, "exec.finalize": 1,
+        "exec.block": 1, "exec.xfer": moves, "exec.finalize": 1,
         "exec.report": 1}
 
 
@@ -96,14 +96,31 @@ def test_spans_nest(traced):
     for name in ("exec.state", "exec.sweep", "exec.finalize", "exec.report"):
         assert all(_inside(sp, execute_) for sp in _named(spans, name)), name
     assert all(_inside(sp, sweeps) for sp in fires)
-    for name in ("exec.dispatch", "exec.block", "exec.xfer"):
+    for name in ("exec.dispatch", "exec.xfer"):
         assert all(_inside(sp, fires) for sp in _named(spans, name)), name
+    dispatches = _named(spans, "exec.dispatch")
     for fire in fires:
-        (d0, d1, *_), = [sp for sp in _named(spans, "exec.dispatch")
-                         if _inside(sp, [fire])]
-        (b0, b1, *_), = [sp for sp in _named(spans, "exec.block")
-                         if _inside(sp, [fire])]
-        assert d1 <= b0
+        assert sum(_inside(sp, [fire]) for sp in dispatches) == 1
+    # The run waits once, after the last firing and before the report.
+    block, = _named(spans, "exec.block")
+    assert _inside(block, execute_) and not _inside(block, sweeps)
+    assert max(sp[1] for sp in fires) <= block[0]
+    (r0, *_), = _named(spans, "exec.report")
+    assert block[1] <= r0
+
+
+def test_no_dispatch_inside_a_block(traced):
+    _, _, _, spans = traced
+    blocks = _named(spans, "exec.block")
+    assert not any(_inside(sp, blocks)
+                   for sp in _named(spans, "exec.dispatch"))
+
+
+def test_dispatch_queued_argument(traced):
+    design, _, result, spans = traced
+    queued = [a["queued"] for *_, a in _named(spans, "exec.dispatch")]
+    assert set(queued) <= {0, 1}
+    assert sum(queued) == result.report.queued_firings
 
 
 def test_outputs_bit_identical_under_the_profiler(traced):
