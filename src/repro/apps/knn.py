@@ -15,7 +15,8 @@ Mechanisms:
 """
 from __future__ import annotations
 
-from typing import Dict
+import functools
+from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -112,24 +113,54 @@ def run_numeric(n: int = 2048, dim: int = 16, q: int = 32, k: int = K,
                   block_n=min(512, n))
 
 
-def _merge_topk(parts, k: int):
-    """Merge per-shard (dists, global_idx) candidates into the k smallest."""
+@functools.partial(jax.jit,
+                   static_argnames=("k", "n_valid", "block_n", "interpret"))
+def knn_shard(queries, shard, offset, *, k: int, n_valid: int, block_n: int,
+              interpret: Optional[bool] = None):
+    """One blue module: the fused distance + top-k kernel over a shard
+    already padded to a multiple of ``block_n`` (its first ``n_valid``
+    rows are points), with local indices made global by ``offset``."""
+    from ..kernels import knn_op
+    d, i = knn_op(queries, shard, k=k, block_n=block_n, n_valid=n_valid,
+                  interpret=interpret)
+    return d, i + offset
+
+
+@functools.partial(jax.jit, static_argnames="k")
+def knn_merge(parts, k: int):
+    """A sorter or the aggregator: the ``k`` smallest of its inputs'
+    ``(dists, global_idx)`` candidates, ascending."""
     d = jnp.concatenate([p[0] for p in parts], axis=1)
     gi = jnp.concatenate([p[1] for p in parts], axis=1)
-    neg_d, pos = jax.lax.top_k(-d, min(k, d.shape[1]))
-    return -neg_d, jnp.take_along_axis(gi, pos, axis=1)
+    d, gi = jax.lax.sort((d, gi), dimension=1, num_keys=1)
+    return d[:, :k], gi[:, :k]
 
 
 def bind_programs(graph: TaskGraph, spec=None):
     """Executable bodies for the CHIP-KNN graph (repro.exec hook).
 
-    Each blue ``dist{b}`` module owns a dataset shard and emits its local
-    top-k candidates (paper Fig. 4: only K survivors per module cross a
-    channel); ``sort{s}`` merges its blues, ``agg`` merges the sorters —
-    the distributed merge of per-shard top-k equals the global top-k.
+    Each blue ``dist{b}`` module owns a contiguous dataset shard (the
+    ranges of ``np.array_split``) and emits its local top-k candidates on
+    the fused Pallas kernel (paper Fig. 4: only K survivors per module
+    cross a channel); ``sort{s}`` merges its blues, ``agg`` merges the
+    sorters, both in the one jitted ``knn_merge`` — the distributed merge
+    of per-shard top-k equals the global top-k.  Shards are sliced and
+    padded to a multiple of the kernel's block once, here, on the host,
+    and a blue's shard is placed on the chip it first fires on: no firing
+    pads, gathers or moves it between chips, and no device holds the
+    dataset beside its shards.  ``reference()`` regenerates the dataset
+    from the seed and runs the plain jnp search in float32 (``knn_ref``,
+    products at ``HIGHEST``), one query batch at a time.
+
+    ``spec`` sets the numeric scale independently of the graph's modeled
+    §5.4 scale: ``n`` points (default 1024; the paper's 4M), ``dim`` (8;
+    16), ``q`` queries a batch (8), ``k`` (``K``), ``streams`` (query
+    batches, 2), ``seed`` and ``interpret`` (the kernel's Pallas interpret
+    mode; None interprets on CPU only).  Every shard must hold ``k``
+    points.
     """
     from ..exec.programs import SOURCE_KEY, ProgramBinding
-    from ..kernels import knn_op
+    from ..kernels.knn.kernel import DEFAULT_BLOCK_N
     from ..kernels.knn.ref import knn_ref
 
     spec = dict(spec or {})
@@ -139,44 +170,52 @@ def bind_programs(graph: TaskGraph, spec=None):
     k = spec.get("k", K)
     streams = spec.get("streams", 2)
     seed = spec.get("seed", 0)
+    interpret = spec.get("interpret")
     blues = sorted((t for t in graph.tasks if t.startswith("dist")),
                    key=lambda t: int(t[len("dist"):]))
     sorters = sorted((t for t in graph.tasks if t.startswith("sort")),
                      key=lambda t: int(t[len("sort"):]))
+    if n < k * len(blues):
+        raise ValueError(f"{n} points over {len(blues)} blues leave a shard "
+                         f"with fewer than k={k}")
 
     rng = jax.random.PRNGKey(seed)
-    data = jax.random.normal(rng, (n, dim), jnp.float32)
+
+    def points():
+        return jax.random.normal(rng, (n, dim), jnp.float32)
+
+    host = np.asarray(points())
     queries = [jax.random.normal(jax.random.fold_in(rng, 1 + t), (q, dim),
                                  jnp.float32) for t in range(streams)]
-    shards = np.array_split(np.arange(n), len(blues))
+    sizes = [len(s) for s in np.array_split(np.arange(n), len(blues))]
+    bounds = np.cumsum([0] + sizes).tolist()
+    block_n = min(DEFAULT_BLOCK_N, -(-max(sizes) // 8) * 8)
+    rows = -(-max(sizes) // block_n) * block_n
 
-    def dist_body(shard_idx):
-        shard = data[jnp.asarray(shard_idx)]
-        gidx = jnp.asarray(shard_idx)
+    def dist_body(lo, hi):
+        shard = np.pad(host[lo:hi], ((0, rows - (hi - lo)), (0, 0)))
+        placed = {}
 
         def body(inputs):
-            d, li = knn_ref(inputs[SOURCE_KEY], shard,
-                            min(k, len(shard_idx)))
-            return d, gidx[li]
+            qs = inputs[SOURCE_KEY]
+            dev = next(iter(qs.devices()))
+            if dev not in placed:
+                placed[dev] = jax.device_put((shard, jnp.int32(lo)), dev)
+            x, offset = placed[dev]
+            return knn_shard(qs, x, offset, k=k, n_valid=hi - lo,
+                             block_n=block_n, interpret=interpret)
         return body
 
-    def merge_body(preds):
-        def body(inputs):
-            return _merge_topk([inputs[p] for p in preds], k)
-        return body
+    def merge_body(inputs):
+        return knn_merge(tuple(inputs.values()), k)
 
-    programs = {}
-    for b, name in enumerate(blues):
-        programs[name] = dist_body(shards[b])
-    for s, name in enumerate(sorters):
-        programs[name] = merge_body(
-            [blues[b] for b in range(len(blues))
-             if b % len(sorters) == s])
-    programs["agg"] = merge_body(sorters)
+    programs = {name: dist_body(bounds[b], bounds[b + 1])
+                for b, name in enumerate(blues)}
+    programs.update({name: merge_body for name in sorters + ["agg"]})
 
     def reference():
-        outs = [knn_op(qs, data, k=k, block_q=min(32, q),
-                       block_n=min(512, n)) for qs in queries]
+        data = points()
+        outs = [knn_ref(qs, data, k) for qs in queries]
         return (jnp.stack([o[0] for o in outs]),
                 jnp.stack([o[1] for o in outs]))
 

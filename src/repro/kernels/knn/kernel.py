@@ -14,7 +14,7 @@ running top-k.
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -37,9 +37,11 @@ def _knn_kernel(q_ref, x_ref, od_ref, oi_ref, best_d, best_i, *,
 
     q = q_ref[...].astype(jnp.float32)            # [BQ, D]
     x = x_ref[...].astype(jnp.float32)            # [BN, D]
-    # Squared L2 via the MXU: |q|² − 2 q·xᵀ + |x|².
+    # Squared L2 via the MXU: |q|² − 2 q·xᵀ + |x|².  HIGHEST keeps the
+    # products in float32: one bfloat16 pass misorders near neighbours.
     d2 = (jnp.sum(q * q, -1, keepdims=True)
           - 2.0 * jax.lax.dot_general(q, x, (((1,), (1,)), ((), ())),
+                                      precision=jax.lax.Precision.HIGHEST,
                                       preferred_element_type=jnp.float32)
           + jnp.sum(x * x, -1)[None, :])          # [BQ, BN]
     gidx = ni * block_n + jax.lax.broadcasted_iota(
@@ -74,14 +76,25 @@ def _knn_kernel(q_ref, x_ref, od_ref, oi_ref, best_d, best_i, *,
 
 def knn(queries: jax.Array, data: jax.Array, k: int = 10,
         block_q: int = DEFAULT_BLOCK_Q, block_n: int = DEFAULT_BLOCK_N,
-        interpret: bool = False) -> Tuple[jax.Array, jax.Array]:
-    """queries: [Q, D]; data: [N, D] → (dists [Q,k], idx [Q,k]) ascending."""
+        n_valid: Optional[int] = None, interpret: bool = False
+        ) -> Tuple[jax.Array, jax.Array]:
+    """queries: [Q, D]; data: [N, D] → (dists [Q,k], idx [Q,k]) ascending.
+
+    ``n_valid``: only the first ``n_valid`` rows of ``data`` are points; the
+    rest is padding that the kernel's tail mask skips, and N must then be a
+    multiple of ``block_n``.  None pads ``data`` here when N is not.
+    """
     Q, D = queries.shape
     N, _ = data.shape
     block_q = min(block_q, Q)
     block_n = min(block_n, N)
     pad_q = (-Q) % block_q
     pad_n = (-N) % block_n
+    if n_valid is None:
+        n_valid = N
+    elif pad_n:
+        raise ValueError(f"data of {N} rows with n_valid={n_valid} is not "
+                         f"padded to a multiple of block_n={block_n}")
     if pad_q:
         queries = jnp.pad(queries, ((0, pad_q), (0, 0)))
     if pad_n:
@@ -89,7 +102,8 @@ def knn(queries: jax.Array, data: jax.Array, k: int = 10,
     Qp, Np = Q + pad_q, N + pad_n
     grid = (Qp // block_q, Np // block_n)
     od, oi = pl.pallas_call(
-        functools.partial(_knn_kernel, k=k, block_n=block_n, n_total=N),
+        functools.partial(_knn_kernel, k=k, block_n=block_n,
+                          n_total=n_valid),
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_q, D), lambda qi, ni: (qi, 0)),

@@ -15,12 +15,13 @@ def _on_cpu() -> bool:
 
 
 @functools.partial(jax.jit, static_argnames=("k", "block_q", "block_n",
-                                             "interpret"))
+                                             "n_valid", "interpret"))
 def knn_op(queries, data, k: int = 10, block_q: int = 128,
-           block_n: int = 512, interpret: Optional[bool] = None):
+           block_n: int = 512, n_valid: Optional[int] = None,
+           interpret: Optional[bool] = None):
     interp = _on_cpu() if interpret is None else interpret
     return knn(queries, data, k=k, block_q=block_q, block_n=block_n,
-               interpret=interp)
+               n_valid=n_valid, interpret=interp)
 
 
 __all__ = ["knn_op", "knn_ref"]

@@ -94,6 +94,44 @@ def test_knn(Q, N, D, k):
     np.testing.assert_allclose(gathered, dr, atol=1e-4, rtol=1e-4)
 
 
+def test_knn_tail_mask_covers_padding():
+    """Data padded beforehand, with rows that would be every query's
+    nearest: ``n_valid`` masks them, and the result is the unpadded one."""
+    q = jax.random.normal(RNG, (16, 16), jnp.float32)
+    x = jax.random.normal(jax.random.fold_in(RNG, 1), (300, 16), jnp.float32)
+    padded = jnp.concatenate([x, jnp.tile(q, (14, 1))[:212]])   # 512 rows
+    d, i = knn_op(q, padded, k=10, block_q=16, block_n=256, n_valid=300)
+    dr, ir = knn_op(q, x, k=10, block_q=16, block_n=256)
+    np.testing.assert_array_equal(d, dr)
+    np.testing.assert_array_equal(i, ir)
+    with pytest.raises(ValueError, match="not padded"):
+        knn_op(q, x, k=10, block_q=16, block_n=256, n_valid=300)
+
+
+# Float32 squared distances of N(0, 1) points in 16-D (about 32) are good
+# to a few 1e-6; one bfloat16 rounding of the inputs moves them by 1e-2.
+KNN_REF_ATOL = 1e-4
+
+
+@pytest.mark.parametrize("dtype,agrees", [(jnp.float32, True),
+                                          (jnp.bfloat16, False)])
+def test_knn_ref_matches_float64(dtype, agrees):
+    """``knn_ref`` in float32 agrees with a float64 NumPy search; the same
+    computation in bfloat16 does not, so the tolerance can tell them."""
+    q = jax.random.normal(RNG, (32, 16), jnp.float32)
+    x = jax.random.normal(jax.random.fold_in(RNG, 1), (2000, 16), jnp.float32)
+    d, i = knn_ref(q.astype(dtype), x.astype(dtype), 10)
+    q64, x64 = np.asarray(q, np.float64), np.asarray(x, np.float64)
+    exact = ((q64[:, None, :] - x64[None]) ** 2).sum(-1)
+    want = np.sort(exact, axis=1)[:, :10]
+    err = np.abs(np.asarray(d, np.float64) - want).max()
+    assert (err <= KNN_REF_ATOL) == agrees, err
+    if agrees:
+        np.testing.assert_allclose(np.take_along_axis(exact, np.asarray(i),
+                                                      1), want,
+                                   atol=KNN_REF_ATOL, rtol=0)
+
+
 # -- systolic matmul ----------------------------------------------------------
 
 @pytest.mark.parametrize("M,K,N,bm,bn,bk", [
