@@ -20,8 +20,9 @@ def knn_op(queries, data, k: int = 10, block_q: int = 128,
            block_n: int = 512, n_valid: Optional[int] = None,
            interpret: Optional[bool] = None):
     interp = _on_cpu() if interpret is None else interpret
-    return knn(queries, data, k=k, block_q=block_q, block_n=block_n,
-               n_valid=n_valid, interpret=interp)
+    d, i, _ = knn(queries, data, k=k, block_q=block_q, block_n=block_n,
+                  n_valid=n_valid, interpret=interp)
+    return d, i
 
 
 __all__ = ["knn_op", "knn_ref"]

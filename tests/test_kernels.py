@@ -6,6 +6,7 @@ import pytest
 
 from repro.kernels.flash_attention.ops import flash_attention_op
 from repro.kernels.flash_attention.ref import attention_ref
+from repro.kernels.knn.kernel import knn
 from repro.kernels.knn.ops import knn_op, knn_ref
 from repro.kernels.stencil_dilate.ops import dilate_iters_ref, dilate_op
 from repro.kernels.systolic_matmul.ops import (conv_im2col_ref, conv_op,
@@ -92,6 +93,90 @@ def test_knn(Q, N, D, k):
     # Indices may permute among ties — compare distances gathered by index.
     gathered = jnp.sum((q[:, None, :] - x[i]) ** 2, -1)
     np.testing.assert_allclose(gathered, dr, atol=1e-4, rtol=1e-4)
+
+
+def _knn_case(order, Q, N):
+    """Queries and points for one gated-selection case: N(0, 1) points,
+    the same points in order of falling distance to the queries (bunched
+    near one centre, so every block holds k candidates for each), or a
+    shuffled copy of each point (ties across blocks)."""
+    ks = jax.random.split(jax.random.fold_in(RNG, 7), 3)
+    if order == "falling":
+        q = 0.01 * jax.random.normal(ks[0], (Q, 16), jnp.float32)
+        x = jax.random.normal(ks[1], (N, 16), jnp.float32)
+        return q, x[jnp.argsort(-jnp.sum(x * x, -1))]
+    q = jax.random.normal(ks[0], (Q, 16), jnp.float32)
+    if order == "duplicated":
+        x = jax.random.normal(ks[1], (N // 2, 16), jnp.float32)
+        return q, jax.random.permutation(ks[2], jnp.concatenate([x, x]))
+    return q, jax.random.normal(ks[1], (N, 16), jnp.float32)
+
+
+def _assert_knn_exact(q, x, d, i, k):
+    """Distances as ``knn_ref``'s; each query's indices distinct, in
+    range, and judged by their exact distance (ties may permute)."""
+    dr, _ = knn_ref(q, x, k)
+    np.testing.assert_allclose(d, dr, atol=1e-4, rtol=1e-4)
+    i = np.asarray(i)
+    assert ((i >= 0) & (i < x.shape[0])).all()
+    assert all(len(set(row)) == k for row in i.tolist())
+    gathered = jnp.sum((q[:, None, :] - x[i]) ** 2, -1)
+    np.testing.assert_allclose(jnp.sort(gathered, 1), dr, atol=1e-4,
+                               rtol=1e-4)
+
+
+@pytest.mark.parametrize("order,Q,N,k,block_q,block_n,pad", [
+    ("falling", 32, 1024, 10, 32, 256, 0),    # every block runs k passes
+    ("duplicated", 16, 1024, 10, 16, 256, 0),  # ties across blocks
+    ("normal", 16, 700, 10, 16, 256, 68),     # padded tail, n_valid
+    ("normal", 40, 1000, 10, 16, 256, 0),     # block_q 16, queries padded
+    ("normal", 64, 1500, 10, 32, 128, 0),     # block_q 32
+    ("normal", 16, 200, 20, 16, 16, 0),       # k over a block's points
+])
+def test_knn_gated_selection(order, Q, N, k, block_q, block_n, pad):
+    """The gated kernel returns the exact top-k; padding rows past
+    ``n_valid``, each a copy of a query, are never candidates."""
+    q, x = _knn_case(order, Q, N)
+    if pad:
+        data = jnp.concatenate([x, jnp.tile(q, (-(-pad // Q), 1))[:pad]])
+        d, i = knn_op(q, data, k=k, block_q=block_q, block_n=block_n,
+                      n_valid=N)
+    else:
+        d, i = knn_op(q, x, k=k, block_q=block_q, block_n=block_n)
+    _assert_knn_exact(q, x, d, i, k)
+
+
+def _gate_passes(q, x, k, block_n):
+    """The gate counted in NumPy: per block, the most over the queries of
+    ``min(k, points strictly below the running k-th)``, summed."""
+    q64, x64 = np.asarray(q, np.float64), np.asarray(x, np.float64)
+    d = ((q64[:, None, :] - x64[None]) ** 2).sum(-1)
+    best = np.full((q.shape[0], k), np.inf)
+    passes = 0
+    for lo in range(0, x.shape[0], block_n):
+        blk = d[:, lo:lo + block_n]
+        passes += min(k, int((blk < best[:, -1:]).sum(1).max()))
+        best = np.sort(np.concatenate([best, blk], 1), 1)[:, :k]
+    return passes
+
+
+@pytest.mark.parametrize("order", ["normal", "falling"])
+def test_knn_passes_counts_the_gate(order):
+    """``passes`` is the gate's count, per query block: fewer than k a
+    block on N(0, 1) points, and k every block when each block holds k
+    candidates for some query."""
+    k, block_n, n_blocks = 10, 128, 16
+    q, x = _knn_case(order, 64, n_blocks * block_n)
+    d, i, passes = knn(q, x, k=k, block_q=32, block_n=block_n,
+                       interpret=True)
+    _assert_knn_exact(q, x, d, i, k)
+    want = [_gate_passes(q[:32], x, k, block_n),
+            _gate_passes(q[32:], x, k, block_n)]
+    assert passes.tolist() == want
+    if order == "falling":
+        assert want == [k * n_blocks] * 2
+    else:
+        assert max(want) < k * n_blocks
 
 
 def test_knn_tail_mask_covers_padding():

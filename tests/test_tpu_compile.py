@@ -58,6 +58,11 @@ KERNELS = {
                [((4096, 4096), jnp.bfloat16), ((4096, 4096), jnp.bfloat16)]),
     "knn": (lambda q, x: knn_op(q, x, k=10, interpret=False),
             [((128, 16), jnp.float32), ((4_000_000, 16), jnp.float32)]),
+    # One blue's shard of the 4M-point design: 55,556 points padded to
+    # whole 512-point blocks.
+    "knn_shard": (lambda q, x: knn_op(q, x, k=10, block_n=512, n_valid=55556,
+                                      interpret=False),
+                  [((128, 16), jnp.float32), ((55808, 16), jnp.float32)]),
     "gemv": (lambda A, x: gemv_op(A, x, interpret=False),
              [((M_GEMV, M_GEMV), jnp.float32), ((1, M_GEMV), jnp.float32)]),
     "dot_partials": (lambda x, y: dot_partials_op(x, y, interpret=False),
