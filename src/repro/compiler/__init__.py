@@ -136,10 +136,6 @@ Register a new pass and name it in ``CompileOptions.passes``::
     def repartition_congested(state):
         ...mutate state.partition...
         return {"moved": n}
-
-The legacy free functions (``repro.core.partition`` /
-``floorplan_device`` / ``pipeline_interconnect``) remain as deprecated
-shims that forward to the same implementations these passes call.
 """
 from .artifact import CompiledDesign, PassRecord
 from .options import CompileOptions

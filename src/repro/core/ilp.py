@@ -11,8 +11,8 @@ Performance notes (PR 3)
   (``data``/``rows``/``cols``) instead of per-row Python dicts, and exposes
   vectorized ``add_vars`` / ``add_rows`` / ``add_le_rows`` / ``add_eq_rows`` /
   ``add_ge_rows`` bulk APIs so the solvers can emit whole constraint blocks
-  as numpy arrays.  The legacy per-row dict API is kept (same semantics) and
-  serves as the build-time baseline in ``benchmarks/perf.py``.
+  as numpy arrays.  The legacy per-row dict API is kept (same semantics);
+  ``tests/test_perf_equivalence.py`` holds the two to the same solutions.
 * :meth:`Model.solve` degrades gracefully under a ``time_limit``: if HiGHS
   stops at the limit with an integer-feasible incumbent, that incumbent is
   returned; otherwise a caller-supplied ``warm_start`` solution (e.g. the KL
@@ -357,8 +357,8 @@ def kl_refine_reference(assign: Dict[str, int],
                         max_passes: int = 8) -> Dict[str, int]:
     """Greedy single-move refinement (original pure-Python implementation).
 
-    Kept verbatim as the golden reference for :func:`kl_refine` and as the
-    baseline timed by ``benchmarks/perf.py``.
+    Kept verbatim as the golden reference for :func:`kl_refine`
+    (``tests/test_kl_refine.py``).
 
     assign: node -> device; edges: (u, v, weight); pair_cost[d1, d2]:
     dist×λ between devices; area[node]: resource vector; caps[d, k]:

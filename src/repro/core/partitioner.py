@@ -25,7 +25,7 @@ device pair (half the w-vars); and a first-fit-decreasing + KL warm start is
 computed up front so a branch-and-cut ``time_limit`` degrades gracefully to a
 feasible solution instead of raising :class:`ILPError`.  The original
 dict-row construction is kept as ``_solve_exact_reference`` — the golden
-baseline for ``benchmarks/perf.py`` and the equivalence tests — selected via
+baseline of ``tests/test_perf_equivalence.py`` — selected via
 ``partition(..., use_reference=True)`` together with
 :func:`repro.core.ilp.kl_refine_reference`.
 """
@@ -131,7 +131,7 @@ def partition(graph: TaskGraph, cluster: Cluster, *,
         ``_areas(graph, kinds)`` — the compiler pipeline memoizes them per
         compile() so repeated passes stop recomputing.
     use_reference: run the legacy dict-row exact model + pure-Python KL
-        refiner (golden baseline for perf/equivalence testing).
+        refiner (golden baseline for the equivalence tests).
     """
     graph.validate()
     t0 = time.perf_counter()
@@ -323,9 +323,8 @@ def _solve_exact(graph: TaskGraph, cluster: Cluster, kinds, balance_kind,
 def _build_exact_model_reference(graph: TaskGraph, cluster: Cluster, kinds,
                                  balance_kind, balance_tol, pins):
     """Original dict-per-row model build (ordered device pairs).  Kept
-    verbatim as the golden baseline: ``benchmarks/perf.py`` times it against
-    :func:`_build_exact_model` and the equivalence tests assert both produce
-    the same Eq. 2 objective."""
+    verbatim as the golden baseline: the equivalence tests assert it and
+    :func:`_build_exact_model` produce the same Eq. 2 objective."""
     ndev = cluster.num_devices
     nodes = graph.task_names()
     areas = _areas(graph, kinds)
@@ -440,8 +439,8 @@ def _two_way_ilp(graph, node_set, left_devs, right_devs, areas, kinds,
 
     ``use_reference`` emits the cut-cost block through the legacy per-edge
     dict-row API (identical vars/rows, so both paths stay deterministic and
-    comparable) — the baseline ``benchmarks/perf.py`` times on the
-    recursive-bisect configs.  ``pair_cost`` overrides the representative
+    comparable) — the baseline ``tests/test_perf_equivalence.py`` checks on
+    the recursive-bisect configs.  ``pair_cost`` overrides the representative
     inter-group edge cost (its [i, j] equals ``cluster.comm_cost(i, j, 1)``
     for the baseline matrix, so passing it is behavior-preserving; the
     congestion_feedback pass passes a calibrated matrix so hot links stay

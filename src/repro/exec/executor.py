@@ -70,7 +70,7 @@ Detection:
   while a sibling in-channel sits *at capacity*: the signature of an
   unbalanced cut-set (§4.6).  Transient during pipeline fill never matches
   (balanced depths leave headroom); persistent imbalance accumulates events
-  until ``starve_limit`` trips :class:`StarvationError` with the channel
+  until :data:`STARVE_LIMIT` trips :class:`StarvationError` with the channel
   that needs more depth.  When the starved input still has tokens in the
   network, the wait is *congestion*, not imbalance — it is tallied in
   ``congestion_waits`` instead of tripping the detector.
@@ -103,6 +103,9 @@ class StarvationError(DeadlockError):
 #: Request ids of ``execute()`` calls in this process (the ``call`` argument
 #: of the ``exec.execute`` span).
 _CALLS = itertools.count()
+
+#: Starvation events at one join that raise :class:`StarvationError`.
+STARVE_LIMIT = 3
 
 #: Sentinel for ``execute(fabric=...)``: use the design's fabric (pass
 #: ``fabric=None`` explicitly to force the ideal transfer path).
@@ -188,8 +191,6 @@ class ExecutionState:
                  inputs: Optional[Mapping[str, Any]] = None,
                  devices: Optional[Sequence[Any]] = None,
                  max_sweeps: Optional[int] = None,
-                 starve_limit: int = 3,
-                 check_starvation: bool = True,
                  fabric: Any = FROM_DESIGN,
                  net_config=None,
                  mem: Any = FROM_DESIGN,
@@ -354,8 +355,6 @@ class ExecutionState:
                 max_sweeps += 256 + 4 * sum(mc.total_bursts()
                                             for mc in self.mem_channels)
         self.max_sweeps = max_sweeps
-        self.starve_limit = starve_limit
-        self.check_starvation = check_starvation
 
         self.fired: Dict[str, int] = {t: 0 for t in graph.tasks}
         self.starve_events: Dict[str, int] = {}
@@ -473,7 +472,7 @@ class ExecutionState:
                             continue
                         # A bounded FIFO may transiently saturate while the
                         # pipeline fills (bounded by the paths' hop-count
-                        # difference) — only persistence past starve_limit
+                        # difference) — only persistence past STARVE_LIMIT
                         # is the unbalanced-cut-set signature.
                         self.starve_events[v] = \
                             self.starve_events.get(v, 0) + 1
@@ -485,9 +484,7 @@ class ExecutionState:
                             "starved_input": f"{empty[0].src}->{v}",
                             "full_input": f"{at_cap[0].src}->{v}",
                             "full_depth": at_cap[0].capacity})
-                        if (self.check_starvation
-                                and self.starve_events[v]
-                                >= self.starve_limit):
+                        if self.starve_events[v] >= STARVE_LIMIT:
                             d = self.starve_detail[-1]
                             raise StarvationError(
                                 f"join {v!r} starved "
@@ -677,8 +674,6 @@ def execute(design: CompiledDesign,
             inputs: Optional[Mapping[str, Any]] = None,
             devices: Optional[Sequence[Any]] = None,
             max_sweeps: Optional[int] = None,
-            starve_limit: int = 3,
-            check_starvation: bool = True,
             fabric: Any = FROM_DESIGN,
             net_config=None,
             mem: Any = FROM_DESIGN,
@@ -722,8 +717,7 @@ def execute(design: CompiledDesign,
         with jax.profiler.TraceAnnotation("exec.state"):
             state = ExecutionState(
                 design, binding, inputs=inputs, devices=devices,
-                max_sweeps=max_sweeps, starve_limit=starve_limit,
-                check_starvation=check_starvation, fabric=fabric,
+                max_sweeps=max_sweeps, fabric=fabric,
                 net_config=net_config, mem=mem, faults=faults,
                 tracer=tracer, device_map=device_map)
         return state.run(injector=injector, checkpoint_dir=checkpoint_dir,

@@ -5,8 +5,8 @@ channels, the graph models per-step channel volumes (``bytes_per_step``),
 and the schedule pass simulates makespan/busy time.  The executor
 *measures*: actual bytes crossing each inter-device channel, FIFO occupancy
 high-water marks, and per-device busy wall time.  This module folds both
-sides into one JSON-ready record so ``benchmarks/perf.py`` can emit a
-measured-vs-predicted section into ``BENCH_compile.json``.
+sides into one JSON-ready record (:meth:`ExecutionReport.summary`), which
+``tests/test_exec.py`` holds to the agreement checks below.
 
 The two hard agreement checks (:meth:`ExecutionReport.agreement`):
 
@@ -46,27 +46,9 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-import warnings
 from typing import Any, Dict, List, Mapping, Optional, Sequence
 
 from .channels import FifoChannel
-
-
-def _deprecated_field(old: str, new: str):
-    """One-release shim: ``report.<old>`` warns and forwards to
-    ``report.<new>`` (the PR 2 pass-registry migration style — read the
-    canonical field, or better, the ``report.metrics`` registry view)."""
-
-    def get(self):
-        warnings.warn(
-            f"ExecutionReport.{old} is deprecated; read "
-            f"ExecutionReport.{new} (or the report.metrics registry view) "
-            f"instead", DeprecationWarning, stacklevel=2)
-        return getattr(self, new)
-
-    get.__name__ = old
-    get.__doc__ = f"Deprecated alias for :attr:`{new}`."
-    return property(get)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -165,13 +147,6 @@ class ExecutionReport:
     # jax device was not yet computed, so the device had work queued ahead
     # (at most firings - 1; 0 where every firing found its device idle).
     queued_firings: int = 0
-
-    # One-release deprecation shims for the pre-registry counter names.
-    congestion_waits = _deprecated_field(
-        "congestion_waits", "task_congestion_waits")
-    mem_waits = _deprecated_field("mem_waits", "task_mem_waits")
-    net_retransmit_bytes = _deprecated_field(
-        "net_retransmit_bytes", "net_retransmit_bytes_total")
 
     @functools.cached_property
     def metrics(self):

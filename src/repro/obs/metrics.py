@@ -32,9 +32,7 @@ The registry is a *view*, not a second source of truth:
 report fields and requires exact equality (ints compare with ``==``,
 floats with ``math.isclose(rel_tol=0, abs_tol=0)`` — i.e. also exact), and
 :func:`assert_trace_report_consistent` closes the loop against the
-recorded trace.  Migrating call sites read
-``report.metrics.total("net.link.retransmit_bytes")`` instead of the
-deprecated ``report.net_retransmit_bytes`` shim.
+recorded trace.
 """
 from __future__ import annotations
 

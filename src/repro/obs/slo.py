@@ -25,8 +25,8 @@ builds, not in the post-mortem.
 
 The monitor is read-only over the substrate: it touches nothing but the
 tracer (reads events, appends alerts), so a monitored run is
-bit-identical to an unmonitored one (``benchmarks/perf.py`` v8 asserts
-identity and bounds the overhead).
+bit-identical to an unmonitored one
+(``tests/test_obs_attrib.py::test_monitor_is_transparent_and_raises_alerts``).
 """
 from __future__ import annotations
 

@@ -14,8 +14,7 @@ anyway).
 The default is :data:`NULL_TRACER`, a :class:`NullTracer` whose ``enabled``
 flag is False and whose emit methods are no-ops: instrumented hot paths
 guard with ``if tracer.enabled:`` so the untraced path allocates nothing
-and stays measurably unchanged (``benchmarks/perf.py`` asserts the
-overhead bound).
+and stays bit-identical to a recording run (``tests/test_obs.py``).
 
 Byte accounting mirrors the counters exactly: per link,
 ``Σ flit_hop bytes − Σ flit_reclassify bytes == LinkCounters.bytes``

@@ -17,15 +17,11 @@ scheduler and driven by open-loop traffic.
     out.conservation            # Σ per-tenant link bytes == totals, exact
     out.record("b").result      # bit-identical to b's solo run
 
-Two fidelity levels, deliberately split:
-
-* :mod:`~repro.tenants.server` co-executes real designs flit by flit and
-  *asserts* the substrate's properties (bit-identity with solo runs,
-  exact conservation, fault drain without collateral damage);
-* :mod:`~repro.tenants.simulate` serves thousands of generated requests
-  (:mod:`~repro.tenants.traffic`) in virtual time over the fluid model of
-  the substrate those assertions validated — the p50/p99/goodput-vs-load
-  curves of the ``serve`` bench section.
+:mod:`~repro.tenants.server` co-executes real designs flit by flit and
+*asserts* the substrate's properties (bit-identity with solo runs, exact
+conservation, fault drain without collateral damage);
+:mod:`~repro.tenants.traffic` generates open-loop request streams and
+:mod:`~repro.tenants.slo` holds the admission controller.
 
 ``python -m repro.tenants.smoke`` is the CI entry point: 2 tenants on 4
 emulated devices, one injected device kill, re-admission on survivors.
@@ -34,16 +30,13 @@ from .recover import (RecoveryPlan, plan_recovery, recompile,
                       shrink_cluster)
 from .server import (DeviceKill, FlowMemory, FlowTransport, ServeOutcome,
                      Tenant, TenantRecord, TenantServer, bit_identical)
-from .simulate import (SimResult, TenantLoad, TenantStats, fair_share,
-                       isolation_check, load_sweep, simulate)
 from .slo import ADMIT, QUEUE, REJECT, SLO, AdmissionController
 from .traffic import Request, TrafficConfig, generate, merge, offered_load
 
 __all__ = [
     "ADMIT", "AdmissionController", "DeviceKill", "FlowMemory",
     "FlowTransport", "QUEUE", "REJECT", "Request", "SLO", "ServeOutcome",
-    "RecoveryPlan", "SimResult", "Tenant", "TenantLoad", "TenantRecord",
-    "TenantServer", "TenantStats", "bit_identical", "fair_share",
-    "generate", "isolation_check", "load_sweep", "merge", "offered_load",
-    "plan_recovery", "recompile", "shrink_cluster", "simulate",
+    "RecoveryPlan", "Tenant", "TenantRecord", "TenantServer",
+    "bit_identical", "generate", "merge", "offered_load", "plan_recovery",
+    "recompile", "shrink_cluster",
 ]

@@ -22,9 +22,8 @@ tight-SLO tenants overtake loose ones as they wait, but a loose-SLO
 request can never be starved forever (its priority grows without bound —
 the aging part).  Ties break deterministically by (arrival, tenant, rid).
 
-The controller is pure bookkeeping over *virtual* time — the serving
-simulation (:mod:`repro.tenants.simulate`) and the live tenant server both
-drive it with their own clocks; it never reads a wall clock.
+The controller is pure bookkeeping over *virtual* time — its caller
+drives it with its own clock; it never reads a wall clock.
 """
 from __future__ import annotations
 

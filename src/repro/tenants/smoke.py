@@ -20,9 +20,6 @@ The run asserts the tentpole's acceptance criteria end to end:
   released), re-compiled onto its surviving device and re-admitted under a
   fresh flow id, and finishes there; tenant ``b`` is bit-identical to its
   solo run anyway;
-* **weighted shares** — the fluid-model oversubscription check
-  (:func:`repro.tenants.isolation_check`) holds at the capacity measured
-  from the co-run;
 * **attribution** — the per-tenant cost ledger
   (:func:`repro.obs.build_ledger`) sums bit-exactly to the global
   counters on both runs, the kill charges tenant ``a``'s lineage
@@ -67,8 +64,7 @@ def main() -> int:
                        assert_peers_uncharged, build_ledger,
                        substrate_metrics)
     from ..obs.trace import Tracer, write_chrome_trace
-    from . import (SLO, DeviceKill, Tenant, TenantServer, bit_identical,
-                   isolation_check)
+    from . import SLO, DeviceKill, Tenant, TenantServer, bit_identical
     from ..runtime.compile_cache import enable_compile_cache
     enable_compile_cache()
 
@@ -163,14 +159,8 @@ def main() -> int:
     assert fby["a"]["cancelled_bytes"] > 0
     assert fby["a"]["restore_sweeps"] > 0
 
-    # -- weighted-share isolation at the measured capacity -------------------
     sweep_time = net_config.sweep_time_s
     duration_s = out.sweeps * sweep_time
-    capacity = conservation["total_link_bytes"] / duration_s
-    iso = isolation_check(capacity)
-    assert iso["isolated"], \
-        f"victim held {iso['victim_share_frac']:.2f} of fair share"
-
     per_tenant = {}
     for rec in out.records:
         per_tenant[rec.name] = {
@@ -181,8 +171,7 @@ def main() -> int:
                 conservation["per_tenant_link_bytes"][rec.name] / duration_s,
         }
     print(f"co-run: {out.sweeps} sweeps, conservation exact, "
-          f"{len(contended)} contended links, "
-          f"victim share {iso['victim_share_frac']:.2f}")
+          f"{len(contended)} contended links")
     for n, row in per_tenant.items():
         print(f"  tenant {n}: latency {row['latency_s']:.2e}s, "
               f"goodput {row['goodput_Bps']:.3e} B/s")
@@ -216,7 +205,6 @@ def main() -> int:
             },
             "attrib": ledger.to_json(),
             "slo": slo_summary,
-            "isolation": iso,
         }, f, indent=2, default=float)
         f.write("\n")
     print(f"SERVE_SMOKE_OK: wrote {args.out}")

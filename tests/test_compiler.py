@@ -1,13 +1,12 @@
 """repro.compiler: golden equivalence vs the legacy hand-wired chain,
-bit-exact unit-normalization round trips, pipeline composition, the
-num_devices fix, and the deprecation shims."""
+bit-exact unit-normalization round trips, pipeline composition, and the
+num_devices fix."""
 import json
 import math
 
 import numpy as np
 import pytest
 
-import repro.core as core
 from repro.apps import knn
 from repro.compiler import (CompileError, CompileOptions, CompilerPipeline,
                             DEFAULT_PASSES)
@@ -177,13 +176,3 @@ def test_explicit_empty_floorplan_device_rejected():
             passes=("normalize_units", "partition", "floorplan"),
             floorplan_devices=(0, 1, 2, 3, 7)))
 
-
-def test_legacy_entry_points_emit_deprecation_warnings():
-    g = linear_graph(2, width_bits=64, area={"LUT": 10.0})
-    cl = fpga_ring_cluster(2)
-    with pytest.warns(DeprecationWarning, match="repro.compiler.compile"):
-        p = core.partition(g, cl)
-    with pytest.warns(DeprecationWarning, match="repro.compiler.compile"):
-        core.floorplan_device(g, g.task_names(), ALVEO_U55C.resources)
-    with pytest.warns(DeprecationWarning, match="repro.compiler.compile"):
-        core.pipeline_interconnect(g, p, cluster=cl)

@@ -268,16 +268,31 @@ def test_hot_bank_diverges_offered_vs_achieved():
 # memory_feedback: stage-1 re-map and stage-2 membound repartition.
 # ---------------------------------------------------------------------------
 
-def test_memory_feedback_remaps_hot_bank():
-    cfg = MemConfig(banks_per_device=2, bank_bandwidth_Bps=1e9)
+def _assert_hot_bank_remapped(cfg):
+    """One reader per bank, each demanding 80% of a bank's per-sweep
+    service, all pinned to bank 0: the re-map must spread them one per
+    bank, cutting max projected utilization by the reader count."""
+    n = cfg.banks_per_device
     per = 0.8 * cfg.bank_bandwidth_Bps * cfg.sweep_time_s
-    g = _readers_graph([per, per], pins=[0, 0])
+    g = _readers_graph([per] * n, pins=[0] * n)
     design = _compile_readers(g, cfg)
     d = design.pass_record("memory_feedback").detail
     assert d["remapped"] and not d["repartitioned"]
-    assert d["max_utilization_before"] == pytest.approx(1.6)
+    assert d["max_utilization_before"] == pytest.approx(0.8 * n)
     assert d["max_utilization_after"] == pytest.approx(0.8)
-    assert design.bank_map["r0"] != design.bank_map["r1"]
+    assert len({design.bank_map[f"r{i}"] for i in range(n)}) == n
+
+
+def test_memory_feedback_remaps_hot_bank():
+    _assert_hot_bank_remapped(MemConfig(banks_per_device=2,
+                                        bank_bandwidth_Bps=1e9))
+
+
+def test_memory_feedback_spreads_sixteen_readers_off_one_bank():
+    """16 readers on bank 0 project to 12.8x overload; spread, 0.8 each."""
+    _assert_hot_bank_remapped(MemConfig(banks_per_device=16,
+                                        bank_bandwidth_Bps=1e9, credits=8,
+                                        burst_bytes=512))
 
 
 def test_membound_repartition_splits_device_aggregate():
@@ -347,10 +362,17 @@ _MEM_OPTS = CompileOptions(
             "pipeline_interconnect", "schedule"))
 
 
-@pytest.mark.parametrize("app", ["axpy", "dot", "gemv", "axpydot"])
-def test_apps_bit_identical_through_banks(app):
-    graph = APPS[app].build_graph(2)
-    design = tapa_compile(graph, fpga_ring_cluster(2), _MEM_OPTS)
+@pytest.mark.parametrize("app,ndev", [
+    pytest.param("axpy", 2, id="axpy"),
+    pytest.param("dot", 2, id="dot"),
+    pytest.param("gemv", 2, id="gemv"),
+    pytest.param("axpydot", 2, id="axpydot"),
+    pytest.param("axpy", 4, id="axpy-4dev"),
+    pytest.param("axpydot", 4, id="axpydot-4dev"),
+])
+def test_apps_bit_identical_through_banks(app, ndev):
+    graph = APPS[app].build_graph(ndev)
+    design = tapa_compile(graph, fpga_ring_cluster(ndev), _MEM_OPTS)
     binding = bind_programs(graph)
     banked = execute(design, binding)
     ideal = execute(design, bind_programs(graph), mem=None)

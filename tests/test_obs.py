@@ -8,12 +8,8 @@ The observability contract, asserted end to end:
 * summed trace-event bytes reconcile with every legacy counter exactly
   (``assert_trace_report_consistent`` / ``assert_registry_consistent``);
 * the exported Chrome trace-event JSON is structurally valid;
-* the critical-path decomposition sums to the measured makespan exactly;
-* the deprecated ``ExecutionReport`` field shims warn once and return the
-  renamed fields' values.
+* the critical-path decomposition sums to the measured makespan exactly.
 """
-import warnings
-
 import jax.numpy as jnp
 import pytest
 
@@ -313,20 +309,3 @@ def test_chaos_drop_cell_attributes_fault_sweeps():
     res = chaos_execute(g, design, faults=drop.fault_model(), tracer=tr2)
     assert_trace_report_consistent(tr2, res.report)
 
-
-# ---------------------------------------------------------------------------
-# Deprecation shims.
-# ---------------------------------------------------------------------------
-
-def test_deprecated_report_fields_warn_and_alias(fabric_run):
-    _, _, _, res, _ = fabric_run
-    rep = res.report
-    for old, new in (("congestion_waits", "task_congestion_waits"),
-                     ("mem_waits", "task_mem_waits"),
-                     ("net_retransmit_bytes", "net_retransmit_bytes_total")):
-        with warnings.catch_warnings(record=True) as w:
-            warnings.simplefilter("always")
-            assert getattr(rep, old) == getattr(rep, new)
-        assert any(issubclass(x.category, DeprecationWarning) for x in w), \
-            old
-        assert any(new in str(x.message) for x in w), old

@@ -2,8 +2,9 @@
 
 The vectorized COO model build + halved linearization + vectorized KL must
 produce the same Eq. 2 partition objective as the legacy dict-row build +
-pure-Python KL (``use_reference=True``) on the paper app graphs — the same
-cross-check ``benchmarks/perf.py`` runs on the full config matrix."""
+pure-Python KL (``use_reference=True``) on the paper app graphs.  The
+8-device cases run at ``exact_limit=1500``: pagerank and knn then take the
+recursive-bisect path, whose ``use_reference`` branch only they reach."""
 import numpy as np
 import pytest
 
@@ -13,19 +14,28 @@ from repro.core.ilp import ILPError, Model
 from repro.core.partitioner import partition
 
 
-@pytest.mark.parametrize("mod,ndev", [
-    (stencil, 2), (stencil, 4),
-    (pagerank, 2), (pagerank, 4),
-    (cnn, 2),
-    (knn, 2),
-], ids=lambda p: getattr(p, "__name__", str(p)).split(".")[-1])
-def test_partition_objective_matches_legacy(mod, ndev):
+def _case(mod, ndev, exact_limit=None):
+    name = f"{mod.__name__.split('.')[-1]}-{ndev}"
+    if exact_limit is not None:
+        name += f"-limit{exact_limit}"
+    return pytest.param(mod, ndev, exact_limit, id=name)
+
+
+@pytest.mark.parametrize("mod,ndev,exact_limit", [
+    _case(stencil, 2), _case(stencil, 4),
+    _case(pagerank, 2), _case(pagerank, 4),
+    _case(cnn, 2),
+    _case(knn, 2),
+    _case(stencil, 8, 1500), _case(pagerank, 8, 1500), _case(knn, 8, 1500),
+])
+def test_partition_objective_matches_legacy(mod, ndev, exact_limit):
     cl = fpga_ring_cluster(ndev)
+    limit = {} if exact_limit is None else {"exact_limit": exact_limit}
     p_new = partition(mod.build_graph(ndev), cl,
-                      balance_kind="LUT", balance_tol=0.8)
+                      balance_kind="LUT", balance_tol=0.8, **limit)
     p_ref = partition(mod.build_graph(ndev), cl,
                       balance_kind="LUT", balance_tol=0.8,
-                      use_reference=True)
+                      use_reference=True, **limit)
     assert p_new.comm_cost == pytest.approx(p_ref.comm_cost, rel=1e-6)
     # The drift invariant holds and Eq. 1 holds on the fast path.
     assert p_new.stats.objective == p_new.comm_cost
